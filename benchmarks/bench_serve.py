@@ -67,6 +67,7 @@ import hashlib
 import json
 import pathlib
 import platform
+import sys
 import threading
 import time
 
@@ -358,13 +359,15 @@ def _serve_multitenant(cfg, params, prompts, ntoks, prios, max_len,
 
 
 def _serve_sharded(smoke: bool):
-    """Tensor-parallel serving on the forced-8-device host: the standard
-    mixed trace at tp=1 vs tp=8 through ``Scheduler(tp=...)``.  Greedy
-    tokens must be bitwise-identical across widths (the exactness
+    """Tensor-parallel serving: the standard mixed trace at tp=1 vs tp=N
+    through ``Scheduler(tp=...)``, N being every device the child sees —
+    8 forced host devices on a CPU, the chips of an accelerator host.
+    Greedy tokens must be bitwise-identical across widths (the exactness
     invariant the ``repro.dist`` serving rules guarantee) and the record
     carries tokens/s AND tokens/s-per-device — on a host CPU the per-
     device figure is the honest one, since 8 forced devices share the
-    same cores."""
+    same cores.  With a single device there is nothing to shard: the
+    record says so and the reason goes to stderr."""
     import dataclasses
 
     import jax
@@ -374,9 +377,15 @@ def _serve_sharded(smoke: bool):
     from repro.serve import Request, Scheduler
 
     n_dev = jax.device_count()
-    if n_dev != 8:
+    if jax.default_backend() == "cpu" and n_dev != 8:
         raise SystemExit(f"sharded child expected 8 forced devices, "
                          f"got {n_dev}")
+    if n_dev < 2:
+        msg = (f"sharded: skipped, tp needs at least 2 devices and "
+               f"{jax.default_backend()} has {n_dev}")
+        print(msg, file=sys.stderr)
+        print(json.dumps({"path": "sharded", "devices": n_dev, "skipped": msg}))
+        return
     cfg, prompts, ntoks = _trace(smoke)
     cfg = dataclasses.replace(cfg, cache_dtype="float32")
     max_len = 64 if smoke else 128
@@ -663,13 +672,17 @@ def main() -> int:
         f"tokens_match_reference={mt['tokens_match_reference']} "
         f"compiles_within_budget={mt['compiles_within_budget']}"
     )
-    print(
-        f"sharded: tp8={shard['tp8']['warm_tokens_per_s']} tok/s "
-        f"({shard['tp8']['warm_tokens_per_s_per_device']}/dev) vs "
-        f"tp1={shard['tp1']['warm_tokens_per_s']} tok/s "
-        f"programs={shard['tp8']['compiled_programs']} "
-        f"tokens_identical={shard['tokens_identical']}"
-    )
+    if "skipped" in shard:
+        print(shard["skipped"])
+    else:
+        tpn = shard[f"tp{shard['devices']}"]
+        print(
+            f"sharded: tp{shard['devices']}={tpn['warm_tokens_per_s']} tok/s "
+            f"({tpn['warm_tokens_per_s_per_device']}/dev) vs "
+            f"tp1={shard['tp1']['warm_tokens_per_s']} tok/s "
+            f"programs={tpn['compiled_programs']} "
+            f"tokens_identical={shard['tokens_identical']}"
+        )
     if not rec["tokens_identical"]:
         print("ERROR: continuous and bucketed paths served different tokens")
         return 1

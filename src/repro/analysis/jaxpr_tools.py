@@ -143,6 +143,15 @@ def find_eqns(closed_jaxpr: Any, primitive_name: str) -> List[Any]:
 # --------------------------- pallas introspection ------------------------
 
 
+def _block_dim(b: Any) -> int:
+    """One entry of a ``BlockMapping.block_shape``: a ``Blocked`` size,
+    or 1 for a ``Squeezed`` dim (one element, indexed like a block)."""
+    if isinstance(b, (int, np.integer)):
+        return int(b)
+    size = getattr(b, "block_size", None)
+    return 1 if size is None else int(size)
+
+
 def pallas_call_specs(closed_jaxpr: Any) -> List[Dict[str, Any]]:
     """Extract, for every ``pallas_call`` reachable from the jaxpr, the
     grid, per-operand block shapes/array shapes/dtypes, the evaluable
@@ -154,14 +163,11 @@ def pallas_call_specs(closed_jaxpr: Any) -> List[Dict[str, Any]]:
         grid = tuple(int(g) for g in gm.grid)
         operands = []
         for bm in gm.block_mappings:
-            sd = bm.array_shape_dtype
+            sd = bm.array_aval
             operands.append({
                 "array_shape": tuple(int(d) for d in sd.shape),
                 "dtype": str(sd.dtype),
-                "block_shape": tuple(
-                    int(b) if isinstance(b, (int, np.integer)) else None
-                    for b in bm.block_shape
-                ),
+                "block_shape": tuple(_block_dim(b) for b in bm.block_shape),
                 "index_map_jaxpr": bm.index_map_jaxpr,
             })
         out.append({

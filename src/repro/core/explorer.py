@@ -385,9 +385,7 @@ def run_islands(
     keys = jax.random.split(key, n_isl)
     pops = jax.vmap(lambda k: nsga2.init_population(row, cfg, k))(keys)
 
-    from repro.dist.compat import shard_map
-
-    body = shard_map(
+    body = jax.shard_map(
         island_body,
         mesh=mesh,
         in_specs=(P(axis), P(axis)),
@@ -468,14 +466,12 @@ def run_islands_multi(
 
         return jax.vmap(one_scenario)(tbl, pops, keys)
 
-    from repro.dist.compat import shard_map
-
     # Logical layout via repro.dist: scenarios on scenario_axis, islands
     # on island_axis; the table's per-scenario params shard with their
     # scenario block.
     scen_spec = ctx.spec(("scenario",), (S,))
     both_spec = ctx.spec(("scenario", "island"), (S, n_isl))
-    body = shard_map(
+    body = jax.shard_map(
         shard_body,
         mesh=mesh,
         in_specs=(scen_spec, both_spec, both_spec),

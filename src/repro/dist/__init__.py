@@ -16,13 +16,13 @@ Public API (see :mod:`repro.dist.sharding` for details):
                           no mesh context is installed).
 * ``logical_for_path``  — parameter-path -> logical axes rules.
 * ``param_sharding_tree`` — param pytree -> ``NamedSharding`` pytree.
-* ``shard_map``         — version-compat wrapper over jax's shard_map.
+* ``make_mesh``         — every mesh of the repo, with ``Auto`` axes.
 """
-from repro.dist.compat import shard_map
 from repro.dist.sharding import (
     MeshContext,
     current,
     logical_for_path,
+    make_mesh,
     param_sharding_tree,
     shard_act,
     use_mesh,
@@ -32,8 +32,8 @@ __all__ = [
     "MeshContext",
     "current",
     "logical_for_path",
+    "make_mesh",
     "param_sharding_tree",
     "shard_act",
-    "shard_map",
     "use_mesh",
 ]

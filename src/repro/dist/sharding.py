@@ -38,7 +38,7 @@ import threading
 from typing import Dict, Optional, Sequence, Tuple
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 Axes = Tuple[str, ...]
 LogicalDims = Sequence[Optional[str]]
@@ -53,6 +53,17 @@ _MODEL_AXES = ("model",)
 _MODEL_LOGICAL = (
     "tp", "heads", "kv_heads", "ff", "d_inner", "experts", "vocab", "seq_sp",
 )
+
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str], devices=None) -> Mesh:
+    """Every mesh of the repo.  Its axes are ``Auto``: the rules here
+    place arrays with ``with_sharding_constraint`` and leave the
+    propagation to GSPMD, which ``jax.make_mesh``'s default ``Explicit``
+    axes refuse."""
+    return jax.make_mesh(
+        tuple(shape), tuple(axes), axis_types=(AxisType.Auto,) * len(axes),
+        devices=devices,
+    )
 
 
 def default_rules(mesh: Mesh) -> Dict[str, Axes]:

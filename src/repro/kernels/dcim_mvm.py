@@ -12,17 +12,25 @@ folds the B_w weight bit-planes.  On TPU the natural mapping is:
   * shift-accumulate  = sum_s 2^(k*s) * (.)
   * result fusion     = sum_b 2^b     * (.)
 
-Signedness is handled exactly with two's-complement correction terms:
-with U = X mod 2^Bx, V = W mod 2^Bw, neg_x = [X<0], neg_w = [W<0]:
+Signedness is two's complement, as in a DCIM macro: the top input slice
+is taken with an arithmetic shift (so it carries the sign) and, for
+signed weights, the top bit-plane has weight -2^(Bw-1):
 
-  X @ W = U@V - 2^Bw * U@neg_w - 2^Bx * neg_x@V + 2^(Bx+Bw) * neg_x@neg_w
+  X = sum_s 2^(k*s) * X_s,   W = sum_b c_b * 2^b * W_b,   c_top = -1
 
-so the kernel's output equals an exact integer matmul — which is what a
-full-precision DCIM macro computes.  The grid is (M/BM, N/BN, K/BK) with
-int32 accumulation over the K dimension in VMEM.
+so X @ W = sum_{b,s} c_b * 2^(b + k*s) * (X_s @ W_b) equals the exact
+integer matmul — which is what a full-precision DCIM macro computes.
+The grid is (M/BM, N/BN, K/BK) with int32 accumulation over the K
+dimension in VMEM.
 
-Validity range: |Y| < 2^31.  Guaranteed when K * 2^(Bx+Bw) < 2^31, e.g.
-any K <= 32768 for INT8 x INT8.
+Each partial matmul runs on the MXU in bf16 with f32 accumulation (the
+MXU takes no int32 operands).  That is exact: slice values satisfy
+|X_s| <= 2^k - 1 <= 255 and W_b is 0 or 1, so every operand is an
+integer bf16 holds exactly, and every partial sum is an integer below
+BK * 2^k <= 2^24, which f32 holds exactly in any summation order.
+
+Validity range: |Y| < 2^31 and k <= 8.  Guaranteed when
+K * 2^(Bx+Bw) < 2^31, e.g. any K <= 32768 for INT8 x INT8.
 """
 from __future__ import annotations
 
@@ -39,39 +47,33 @@ BLOCK_K = 128
 
 
 def _dcim_mvm_kernel(x_ref, w_ref, out_ref, *, B_x, B_w, k, x_signed, w_signed):
-    x = x_ref[...].astype(jnp.int32)            # (BM, BK)
-    w = w_ref[...].astype(jnp.int32)            # (BK, BN)
+    x = x_ref[...]                              # (BM, BK) int32
+    w = w_ref[...]                              # (BK, BN) int32
+    n_slices = -(-B_x // k)                     # ceil(B_x / k)
 
-    # Two's-complement unsigned views.
-    U = jnp.bitwise_and(x, (1 << B_x) - 1)
-    V = jnp.bitwise_and(w, (1 << B_w) - 1)
+    def x_slice(s):
+        shifted = jnp.right_shift(x, s * k)
+        if x_signed and s == n_slices - 1:
+            return shifted                      # signed top slice
+        return jnp.bitwise_and(shifted, (1 << k) - 1)
 
     def dot(a, b):
-        # int32 x int32 -> int32 contraction; the MXU path on TPU.
+        # Small-integer operands, exact in bf16; f32 accumulation is
+        # exact below 2^24 (module docstring).
+        bf = lambda t: t.astype(jnp.float32).astype(jnp.bfloat16)
         return jax.lax.dot_general(
-            a, b, (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32
-        )
+            bf(a), bf(b), (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ).astype(jnp.int32)
 
-    n_slices = -(-B_x // k)                      # ceil(B_x / k)
     acc = jnp.zeros(out_ref.shape, jnp.int32)
     # Result fusion over weight bit-planes x shift-accumulate over slices.
     for b in range(B_w):
-        v_plane = jnp.bitwise_and(jnp.right_shift(V, b), 1)
+        w_plane = jnp.bitwise_and(jnp.right_shift(w, b), 1)
+        negative = w_signed and b == B_w - 1    # two's-complement sign plane
         for s in range(n_slices):
-            u_slice = jnp.bitwise_and(
-                jnp.right_shift(U, s * k), (1 << k) - 1
-            )
-            acc = acc + (dot(u_slice, v_plane) << (b + s * k))
-
-    # Sign-correction matmuls (exact two's complement).
-    if w_signed:
-        neg_w = (w < 0).astype(jnp.int32)
-        acc = acc - (dot(U, neg_w) << B_w)
-    if x_signed:
-        neg_x = (x < 0).astype(jnp.int32)
-        acc = acc - (dot(neg_x, V) << B_x)
-        if w_signed:
-            acc = acc + (dot(neg_x, neg_w) << (B_x + B_w))
+            term = dot(x_slice(s), w_plane) << (b + s * k)
+            acc = acc - term if negative else acc + term
 
     @pl.when(pl.program_id(2) == 0)
     def _init():
@@ -100,7 +102,7 @@ def dcim_mvm_pallas(
     block_m: int = BLOCK_M,
     block_n: int = BLOCK_N,
     block_k: int = BLOCK_K,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     """Exact integer matmul via the DCIM bit-serial dataflow.
 
@@ -111,6 +113,7 @@ def dcim_mvm_pallas(
     M, K = x.shape
     K2, N = w.shape
     assert K == K2, (x.shape, w.shape)
+    assert 1 <= k <= 8, f"slice width k={k} outside the exact range 1..8"
     Mp = pl.cdiv(M, block_m) * block_m
     Np = pl.cdiv(N, block_n) * block_n
     Kp = pl.cdiv(K, block_k) * block_k

@@ -44,7 +44,7 @@ def _prealign_kernel(x_ref, mant_ref, emax_ref, *, B_M):
     aligned = jnp.right_shift(mant, shift)           # arithmetic shift
 
     mant_ref[...] = aligned
-    emax_ref[...] = emax[..., 0]
+    emax_ref[...] = emax                             # (BM, BG, 1)
 
 
 @functools.partial(
@@ -56,7 +56,7 @@ def fp_prealign_pallas(
     B_M: int = 8,
     block_rows: int = BLOCK_ROWS,
     block_groups: int = BLOCK_GROUPS,
-    interpret: bool = True,
+    interpret: bool = False,
 ):
     """x: (M, G, H) float32 -> (aligned int32 mantissas (M, G, H),
     biased group exponents (M, G) int32)."""
@@ -75,12 +75,14 @@ def fp_prealign_pallas(
         ],
         out_specs=[
             pl.BlockSpec((block_rows, block_groups, H), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((block_rows, block_groups), lambda i, j: (i, j)),
+            # A trailing unit dim keeps the exponent block legal for the
+            # TPU tiling (its last dim equals the array's).
+            pl.BlockSpec((block_rows, block_groups, 1), lambda i, j: (i, j, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((Mp, Gp, H), jnp.int32),
-            jax.ShapeDtypeStruct((Mp, Gp), jnp.int32),
+            jax.ShapeDtypeStruct((Mp, Gp, 1), jnp.int32),
         ],
         interpret=interpret,
     )(xp)
-    return mant[:M, :G], emax[:M, :G]
+    return mant[:M, :G], emax[:M, :G, 0]

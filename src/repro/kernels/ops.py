@@ -22,6 +22,9 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as _P
+
+from repro.dist import sharding as _shd
 
 from . import dcim_mvm as _mvm
 from . import fp_prealign as _pre
@@ -59,6 +62,35 @@ def resolve_attn_backend(backend=None) -> AttnBackend:
     return b
 
 
+def _per_device_heads(kernel, args, head_axes, n_kv):
+    """Run a Pallas attention kernel under the active mesh context.
+
+    XLA cannot partition a Mosaic kernel, so inside a serving mesh each
+    device runs the kernel in ``shard_map`` on the KV heads it holds
+    (``head_axes[i]`` is the head axis of ``args[i]``, None for an
+    argument every device holds whole; the output's head axis is 2).
+    Attention never mixes heads, so this is the unsharded result.
+    Without a context, or where ``n_kv`` (None for MLA, whose latent
+    cache is replicated) does not split over the mesh, it runs whole."""
+    ctx = _shd.current()
+    if ctx is None:
+        return kernel(*args)
+    axes = None if n_kv is None else ctx.axes_for("kv_heads", n_kv)
+
+    def spec(ndim, axis):
+        if axis is None or not axes:
+            return _P()
+        entries = [None] * ndim
+        entries[axis] = axes[0] if len(axes) == 1 else axes
+        return _P(*entries)
+
+    return jax.shard_map(
+        kernel, mesh=ctx.mesh,
+        in_specs=tuple(spec(a.ndim, ax) for a, ax in zip(args, head_axes)),
+        out_specs=spec(4, 2), check_vma=False,
+    )(*args)
+
+
 def paged_decode_gqa(q, k_pages, v_pages, block_table, pos, backend=None):
     """Paged GQA decode attention: (B, 1, H, hd) q against the slot's
     pages.  XLA: gather a contiguous per-slot view, run
@@ -73,9 +105,13 @@ def paged_decode_gqa(q, k_pages, v_pages, block_table, pos, backend=None):
             _attn._gather_pages(v_pages, block_table),
             pos,
         )
-    return _pa.paged_decode_gqa_pallas(
-        q, k_pages, v_pages, block_table, pos,
+    kernel = functools.partial(
+        _pa.paged_decode_gqa_pallas,
         interpret=b is AttnBackend.PALLAS_INTERPRET,
+    )
+    return _per_device_heads(
+        kernel, (q, k_pages, v_pages, block_table, pos),
+        (2, 2, 2, None, None), k_pages.shape[2],
     )
 
 
@@ -94,10 +130,12 @@ def paged_decode_mla(q_abs, q_rope, ckv_pages, krope_pages, block_table,
             _attn._gather_pages(krope_pages, block_table),
             pos, scale,
         )
-    return _pa.paged_decode_mla_pallas(
-        q_abs, q_rope, ckv_pages, krope_pages, block_table, pos, scale,
+    kernel = functools.partial(
+        _pa.paged_decode_mla_pallas, scale=scale,
         interpret=b is AttnBackend.PALLAS_INTERPRET,
     )
+    args = (q_abs, q_rope, ckv_pages, krope_pages, block_table, pos)
+    return _per_device_heads(kernel, args, (None,) * len(args), None)
 
 
 def prefix_prefill(q, k_ctx, v_ctx, k_tail, v_tail, ctx_len, backend=None):
@@ -117,10 +155,18 @@ def prefix_prefill(q, k_ctx, v_ctx, k_tail, v_tail, ctx_len, backend=None):
             jnp.concatenate([v_ctx, v_tail], axis=1),
             ctx_len, k_ctx.shape[1],
         )
-    return _pa.prefix_prefill_pallas(
-        q, k_ctx, v_ctx, k_tail, v_tail, ctx_len,
-        interpret=b is AttnBackend.PALLAS_INTERPRET,
-    )
+    interpret = b is AttnBackend.PALLAS_INTERPRET
+    if k_ctx is None:
+        def kernel(q, k_tail, v_tail, ctx_len):
+            return _pa.prefix_prefill_pallas(
+                q, None, None, k_tail, v_tail, ctx_len, interpret=interpret)
+        args, axes = (q, k_tail, v_tail, ctx_len), (2, 2, 2, None)
+    else:
+        kernel = functools.partial(_pa.prefix_prefill_pallas,
+                                   interpret=interpret)
+        args = (q, k_ctx, v_ctx, k_tail, v_tail, ctx_len)
+        axes = (2, 2, 2, 2, 2, None)
+    return _per_device_heads(kernel, args, axes, k_tail.shape[2])
 
 
 # --- pareto_rank -------------------------------------------------------------

@@ -2,10 +2,11 @@
 
 The NSGA-II hot spot is the O(P^2 * M) dominance computation performed
 every generation.  On TPU we tile the P x P comparison space into
-(BI, BJ) VMEM blocks; each grid cell loads a (BI, M) and a (BJ, M) strip
-of the objective matrix (M is tiny — 4 for SEGA-DCIM), broadcasts to
-(BI, BJ, M) in VREGs and reduces over M on the VPU.  Output is an int8
-matrix D with D[i, j] == 1 iff candidate i constrained-dominates j.
+(BI, BJ) VMEM blocks; each grid cell loads a (BI, M) strip and a
+transposed (M, BJ) strip of the objective matrix (M is tiny — 4 for
+SEGA-DCIM) and folds the M objectives into (BI, BJ) compares on the
+VPU, one objective at a time.  Output is an int8 matrix D with
+D[i, j] == 1 iff candidate i constrained-dominates j.
 
 Constrained domination (Deb 2002) folds the violation scalar in:
   i dominates j  <=>  (feas_i & feas_j & pareto_dom(i, j)) | (v_i < v_j)
@@ -24,20 +25,23 @@ BLOCK_I = 128
 BLOCK_J = 128
 
 
-def _dominance_kernel(fi_ref, fj_ref, vi_ref, vj_ref, out_ref):
+def _dominance_kernel(fi_ref, fjt_ref, vi_ref, vj_ref, out_ref):
     fi = fi_ref[...]          # (BI, M) objectives of candidates i
-    fj = fj_ref[...]          # (BJ, M) objectives of candidates j
-    vi = vi_ref[...]          # (BI,)   constraint violation of i
-    vj = vj_ref[...]          # (BJ,)   violation of j
+    fjt = fjt_ref[...]        # (M, BJ) objectives of candidates j, transposed
+    vi = vi_ref[...]          # (BI, 1) constraint violation of i
+    vj = vj_ref[...]          # (1, BJ) violation of j
 
-    le = jnp.all(fi[:, None, :] <= fj[None, :, :], axis=-1)   # (BI, BJ)
-    lt = jnp.any(fi[:, None, :] < fj[None, :, :], axis=-1)
+    # One (BI, BJ) compare per objective: an i column against a j row,
+    # so every operand stays 2-D with the j axis on the lanes.
+    le = lt = None
+    for m in range(fi.shape[1]):
+        a, b = fi[:, m:m + 1], fjt[m:m + 1, :]
+        le = (a <= b) if le is None else le & (a <= b)
+        lt = (a < b) if lt is None else lt | (a < b)
     pdom = le & lt
 
-    feas_i = (vi <= 0.0)[:, None]
-    feas_j = (vj <= 0.0)[None, :]
-    cdom = (feas_i & feas_j & pdom) | (vi[:, None] < vj[None, :])
-    out_ref[...] = cdom.astype(jnp.int8)
+    cdom = ((vi <= 0.0) & (vj <= 0.0) & pdom) | (vi < vj)
+    out_ref[...] = jnp.where(cdom, 1, 0).astype(jnp.int8)
 
 
 @functools.partial(jax.jit, static_argnames=("block_i", "block_j", "interpret"))
@@ -46,7 +50,7 @@ def dominance_matrix_pallas(
     violation: jnp.ndarray | None = None,
     block_i: int = BLOCK_I,
     block_j: int = BLOCK_J,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     """(P, M) objectives [+ (P,) violation] -> (P, P) int8 dominance matrix.
 
@@ -74,12 +78,12 @@ def dominance_matrix_pallas(
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_i, M), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_j, M), lambda i, j: (j, 0)),
-            pl.BlockSpec((block_i,), lambda i, j: (i,)),
-            pl.BlockSpec((block_j,), lambda i, j: (j,)),
+            pl.BlockSpec((M, block_j), lambda i, j: (0, j)),
+            pl.BlockSpec((block_i, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((1, block_j), lambda i, j: (0, j)),
         ],
         out_specs=pl.BlockSpec((block_i, block_j), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Ppad, Ppad), jnp.int8),
         interpret=interpret,
-    )(Fp, Fp, vp, vp)
+    )(Fp, Fp.T, vp[:, None], vp[None, :])
     return out[:P, :P]

@@ -10,10 +10,16 @@ through once and y streams out once — optimal HBM traffic.
 Grid: (batch, d_inner/bd, S/st) with the sequence dimension innermost;
 the VMEM scratch state persists across the sequential S grid steps (the
 standard TPU accumulator pattern).  Inside a block a fori_loop walks the
-st timesteps with (bd, N) VPU updates:
+st timesteps with (N, bd) VPU updates:
 
     h   = exp(dt * A) * h + (dt * u) * B_t
     y_t = h . C_t + D * u_t
+
+The state is kept transposed, (N, bd), so that d_inner sits on the
+lanes: each step reads one (1, bd) row of u and dt from its block, and
+only the (1, N) rows of B and C need turning into (N, 1) columns, which
+an identity-masked lane reduction does exactly.  The wrapper transposes
+A, h0 and the returned state to and from that layout.
 
 Validated against the pure-jnp sequential oracle (ref.selective_scan_ref)
 and cross-checked against the associative-scan path in models/mamba.py.
@@ -31,34 +37,37 @@ BLOCK_D = 256
 BLOCK_S = 512
 
 
-def _scan_kernel(u_ref, dt_ref, b_ref, c_ref, a_ref, d_ref, h0_ref,
+def _scan_kernel(u_ref, dt_ref, b_ref, c_ref, at_ref, d_ref, h0_ref,
                  y_ref, hout_ref, h_scr):
     s_idx = pl.program_id(2)
 
     @pl.when(s_idx == 0)
     def _init():
-        h_scr[...] = h0_ref[0]                        # (bd, N)
+        h_scr[...] = h0_ref[0]                        # (N, bd)
 
-    u = u_ref[0]                                      # (st, bd)
-    dt = dt_ref[0]                                    # (st, bd)
-    bmat = b_ref[0]                                   # (st, N)
-    cmat = c_ref[0]                                   # (st, N)
-    a = a_ref[...]                                    # (bd, N)
-    d = d_ref[...]                                    # (bd,)
-    st = u.shape[0]
+    at = at_ref[...]                                  # (N, bd)
+    d = d_ref[...]                                    # (1, bd)
+    N = at.shape[0]
+    st = u_ref.shape[1]
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (N, N), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (N, N), 1))
 
-    def step(t, carry):
-        h, y = carry
-        dt_t = dt[t][:, None]                         # (bd, 1)
-        u_t = u[t][:, None]
-        h = jnp.exp(dt_t * a) * h + (dt_t * u_t) * bmat[t][None, :]
-        y_t = jnp.sum(h * cmat[t][None, :], axis=-1) + d * u[t]
-        return h, y.at[t].set(y_t)
+    def column(row):                                  # (1, N) -> (N, 1)
+        return jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
 
-    y0 = jnp.zeros((st, u.shape[1]), jnp.float32)
-    h, y = jax.lax.fori_loop(0, st, step, (h_scr[...], y0))
+    def step(t, h):
+        u_t = u_ref[0, pl.ds(t, 1), :]                # (1, bd)
+        dt_t = dt_ref[0, pl.ds(t, 1), :]
+        b_t = column(b_ref[0, pl.ds(t, 1), :])        # (N, 1)
+        c_t = column(c_ref[0, pl.ds(t, 1), :])
+        h = jnp.exp(dt_t * at) * h + (dt_t * u_t) * b_t
+        y_ref[0, pl.ds(t, 1), :] = (
+            jnp.sum(h * c_t, axis=0, keepdims=True) + d * u_t
+        )
+        return h
+
+    h = jax.lax.fori_loop(0, st, step, h_scr[...])
     h_scr[...] = h
-    y_ref[0] = y
     hout_ref[0] = h
 
 
@@ -75,7 +84,7 @@ def selective_scan_pallas(
     h0: jnp.ndarray | None = None,   # (B, D, N)
     block_d: int = BLOCK_D,
     block_s: int = BLOCK_S,
-    interpret: bool = True,
+    interpret: bool = False,
 ):
     """Returns (y (B, S, D) f32, h_last (B, D, N) f32)."""
     Bsz, S, D = u.shape
@@ -94,24 +103,24 @@ def selective_scan_pallas(
             pl.BlockSpec((1, st, bd), lambda b, di, si: (b, si, di)),  # dt
             pl.BlockSpec((1, st, N), lambda b, di, si: (b, si, 0)),    # B
             pl.BlockSpec((1, st, N), lambda b, di, si: (b, si, 0)),    # C
-            pl.BlockSpec((bd, N), lambda b, di, si: (di, 0)),          # A
-            pl.BlockSpec((bd,), lambda b, di, si: (di,)),              # D
-            pl.BlockSpec((1, bd, N), lambda b, di, si: (b, di, 0)),    # h0
+            pl.BlockSpec((N, bd), lambda b, di, si: (0, di)),          # A^T
+            pl.BlockSpec((1, bd), lambda b, di, si: (0, di)),          # D
+            pl.BlockSpec((1, N, bd), lambda b, di, si: (b, 0, di)),    # h0^T
         ],
         out_specs=[
             pl.BlockSpec((1, st, bd), lambda b, di, si: (b, si, di)),
-            pl.BlockSpec((1, bd, N), lambda b, di, si: (b, di, 0)),
+            pl.BlockSpec((1, N, bd), lambda b, di, si: (b, 0, di)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((Bsz, S, D), jnp.float32),
-            jax.ShapeDtypeStruct((Bsz, D, N), jnp.float32),
+            jax.ShapeDtypeStruct((Bsz, N, D), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((bd, N), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((N, bd), jnp.float32)],
         interpret=interpret,
     )(
         u.astype(jnp.float32), dt.astype(jnp.float32),
         B_c.astype(jnp.float32), C_c.astype(jnp.float32),
-        A.astype(jnp.float32), D_skip.astype(jnp.float32),
-        h0.astype(jnp.float32),
+        A.astype(jnp.float32).T, D_skip.astype(jnp.float32)[None, :],
+        h0.astype(jnp.float32).swapaxes(1, 2),
     )
-    return y, h_last
+    return y, h_last.swapaxes(1, 2)

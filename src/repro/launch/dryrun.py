@@ -348,7 +348,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         return rec
 
     if tp:
-        mesh = jax.make_mesh((tp,), ("model",))
+        mesh = shd.make_mesh((tp,), ("model",))
     else:
         mesh = make_production_mesh(multi_pod=multi_pod)
     cfg = entry.config(**(overrides or {}))

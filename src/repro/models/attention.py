@@ -190,11 +190,16 @@ def prefix_attention(
     B, T, H, hd = q.shape
     Hk = k_all.shape[2]
     G = H // Hk
+    hdv = v_all.shape[-1]
     scale = 1.0 / math.sqrt(hd)
-    qg = (q * scale).reshape(B, T, Hk, G, hd)
+    # Head-major, with each KV head's (query, group) pairs as the rows
+    # of one matrix: the layout the fused Pallas kernel computes in.
+    qg = (q * scale).reshape(B, T, Hk, G, hd).transpose(0, 2, 1, 3, 4)
+    qg = qg.reshape(B, Hk, T * G, hd)
     s = jnp.einsum(
-        "bqhgd,bshd->bhgqs", qg, k_all, preferred_element_type=jnp.float32
-    )                                                   # (B, Hk, G, T, L+T)
+        "bhmd,bhsd->bhms", qg, k_all.transpose(0, 2, 1, 3),
+        preferred_element_type=jnp.float32,
+    )                                                   # (B, Hk, T*G, L+T)
     mask_ctx = jnp.arange(L)[None, :] < ctx_len[:, None]          # (B, L)
     mask_tail = jnp.arange(T)[None, :] <= jnp.arange(T)[:, None]  # (T, T)
     mask = jnp.concatenate(
@@ -204,13 +209,15 @@ def prefix_attention(
         ],
         axis=-1,
     )                                                   # (B, T, L+T)
-    s = jnp.where(mask[:, None, None], s, NEG_INF)
+    mask = jnp.repeat(mask, G, axis=1)[:, None]         # (B, 1, T*G, L+T)
+    s = jnp.where(mask, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum(
-        "bhgqs,bshd->bqhgd", p.astype(v_all.dtype), v_all,
+        "bhms,bhsd->bhmd", p.astype(v_all.dtype), v_all.transpose(0, 2, 1, 3),
         preferred_element_type=jnp.float32,
-    )
-    return o.reshape(B, T, H, v_all.shape[-1])
+    )                                                   # (B, Hk, T*G, hdv)
+    o = o.reshape(B, Hk, T, G, hdv).transpose(0, 2, 1, 3, 4)
+    return o.reshape(B, T, H, hdv)
 
 
 def _gather_pages(pages: jnp.ndarray, block_table: jnp.ndarray) -> jnp.ndarray:

@@ -125,14 +125,17 @@ def _pallas_scan(p, u, cfg, valid_len=None):
     D_skip = p["D"]
 
     def run(u_, dt_, b_, c_, a_, d_):
-        y, h = selective_scan_pallas(u_, dt_, b_, c_, a_, d_)
+        # Compiled on TPU; off TPU the interpreter runs the same kernel.
+        y, h = selective_scan_pallas(
+            u_, dt_, b_, c_, a_, d_,
+            interpret=jax.default_backend() != "tpu",
+        )
         return y, h
 
     ctx = shd.current()
     if ctx is None:
         return run(u, dt, Bc, Cc, A, D_skip)
 
-    from repro.dist.compat import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = ctx.mesh
@@ -142,7 +145,7 @@ def _pallas_scan(p, u, cfg, valid_len=None):
     dspec = ctx.spec(("d_inner",), D_skip.shape)
     hspec = ctx.spec(("batch", "d_inner", None),
                      (u.shape[0], u.shape[2], A.shape[1]))
-    return shard_map(
+    return jax.shard_map(
         run, mesh=mesh,
         in_specs=(bspec, bspec, sspec, sspec, aspec, dspec),
         out_specs=(bspec, hspec),

@@ -24,4 +24,5 @@ from .scheduler import (  # noqa: F401
     SlotAllocator,
     StreamHandle,
     default_prefill_buckets,
+    init_params,
 )

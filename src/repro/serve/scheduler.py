@@ -1434,6 +1434,23 @@ class ServeSession:
             pass
 
 
+def init_params(cfg: LMConfig, key, mesh=None):
+    """``lm.init`` as one jitted program whose outputs land where
+    serving keeps them: laid out by ``serve_param_sharding_tree`` over
+    a tensor-parallel ``mesh``, or on the default device without one.
+    A model larger than one chip is thus never whole on any device, as
+    it would be if built eagerly and then placed; and no device holds
+    the per-layer arrays beside their stacked copy, as eager
+    ``lm.init`` does while it stacks them."""
+    init = partial(lm.init, cfg=cfg)
+    if mesh is None:
+        return jax.jit(init)(key)
+    shapes = jax.eval_shape(init, key)
+    return jax.jit(
+        init, out_shardings=shd.serve_param_sharding_tree(shapes, mesh)
+    )(key)
+
+
 class Scheduler:
     """Continuous-batching engine over a paged KV-cache pool.
 
@@ -1505,7 +1522,7 @@ class Scheduler:
                 raise ValueError(
                     f"tp={tp} exceeds {jax.device_count()} visible device(s)"
                 )
-            mesh = jax.make_mesh((tp,), ("model",))
+            mesh = shd.make_mesh((tp,), ("model",))
         self.mesh = mesh
         self.mesh_ctx = None if mesh is None else shd.serving_context(mesh)
         if self.mesh is not None:

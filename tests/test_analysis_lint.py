@@ -237,7 +237,7 @@ def _precision(built):
 def test_precision_hidden_f64_fixture():
     # x64 enabled during tracing: a single f64 constant promotes the
     # whole chain — the forbidden-dtype rule must name the dtype.
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         jaxpr = jax.make_jaxpr(
             lambda x: x * np.float64(2.0)
         )(jnp.zeros(4, jnp.float64))

@@ -162,8 +162,10 @@ class TestDcimMvmKernel:
         rng = np.random.default_rng(5)
         x = jnp.asarray(rng.integers(-128, 128, size=(40, 200)).astype(np.int32))
         w = jnp.asarray(rng.integers(-128, 128, size=(200, 30)).astype(np.int32))
-        a = np.asarray(dcim_mvm_pallas(x, w, block_m=128, block_n=128, block_k=128))
-        b = np.asarray(dcim_mvm_pallas(x, w, block_m=16, block_n=8, block_k=32))
+        a = np.asarray(dcim_mvm_pallas(x, w, block_m=128, block_n=128, block_k=128,
+                                       interpret=True))
+        b = np.asarray(dcim_mvm_pallas(x, w, block_m=16, block_n=8, block_k=32,
+                                       interpret=True))
         np.testing.assert_array_equal(a, b)
 
 
@@ -175,7 +177,7 @@ class TestFpPrealignKernel:
         x = jnp.asarray(
             (rng.normal(size=shape) * 10.0 ** rng.integers(-3, 4, size=shape)).astype(np.float32)
         )
-        m1, e1 = fp_prealign_pallas(x, B_M=B_M)
+        m1, e1 = fp_prealign_pallas(x, B_M=B_M, interpret=True)
         m2, e2 = ref.fp_prealign_ref(x, B_M=B_M)
         np.testing.assert_array_equal(np.asarray(m1), np.asarray(m2))
         np.testing.assert_array_equal(np.asarray(e1), np.asarray(e2))
@@ -184,7 +186,7 @@ class TestFpPrealignKernel:
         x = jnp.asarray(
             [[[0.0, -1.5, 3.25, -0.0, 1e-30, 7.0, -128.0, 0.5]]], jnp.float32
         )
-        m1, e1 = fp_prealign_pallas(x, B_M=8)
+        m1, e1 = fp_prealign_pallas(x, B_M=8, interpret=True)
         m2, e2 = ref.fp_prealign_ref(x, B_M=8)
         np.testing.assert_array_equal(np.asarray(m1), np.asarray(m2))
         np.testing.assert_array_equal(np.asarray(e1), np.asarray(e2))
@@ -194,7 +196,7 @@ class TestFpPrealignKernel:
         (shift 0); every aligned mantissa is bounded by 2^B_M."""
         rng = np.random.default_rng(2)
         x = jnp.asarray(rng.normal(size=(4, 5, 32)).astype(np.float32))
-        m, e = fp_prealign_pallas(x, B_M=8)
+        m, e = fp_prealign_pallas(x, B_M=8, interpret=True)
         m = np.asarray(m)
         assert np.all(np.abs(m) < 2**8)
         assert np.all(np.max(np.abs(m), axis=-1) >= 2**7)  # hidden bit of max
@@ -206,7 +208,7 @@ class TestFpPrealignKernel:
         (one ULP of the aligned grid, from truncation)."""
         rng = np.random.default_rng(seed)
         x = np.asarray(rng.normal(size=(3, 2, 16)).astype(np.float32))
-        m, e = fp_prealign_pallas(jnp.asarray(x), B_M=B_M)
+        m, e = fp_prealign_pallas(jnp.asarray(x), B_M=B_M, interpret=True)
         m, e = np.asarray(m, np.float64), np.asarray(e)
         scale = 2.0 ** (e[..., None] - 127.0 - (B_M - 1))
         recon = m * scale
@@ -308,7 +310,8 @@ class TestSelectiveScanKernel:
         A = jnp.asarray(-np.abs(rng.normal(size=(D, N))).astype(np.float32))
         Ds = jnp.asarray(rng.normal(size=(D,)).astype(np.float32))
         y1, h1 = selective_scan_pallas(u, dt, Bc, Cc, A, Ds,
-                                       block_d=min(16, D), block_s=min(16, S))
+                                       block_d=min(16, D), block_s=min(16, S),
+                                       interpret=True)
         y2, h2 = ref.selective_scan_ref(u, dt, Bc, Cc, A, Ds)
         np.testing.assert_allclose(np.asarray(y1), np.asarray(y2), atol=2e-5)
         np.testing.assert_allclose(np.asarray(h1), np.asarray(h2), atol=2e-5)
@@ -326,8 +329,10 @@ class TestSelectiveScanKernel:
             jnp.asarray(-np.abs(rng.normal(size=(D, N))).astype(np.float32)),
             jnp.asarray(rng.normal(size=(D,)).astype(np.float32)),
         )
-        y1, h1 = selective_scan_pallas(*args, block_d=32, block_s=64)
-        y2, h2 = selective_scan_pallas(*args, block_d=8, block_s=16)
+        y1, h1 = selective_scan_pallas(*args, block_d=32, block_s=64,
+                                       interpret=True)
+        y2, h2 = selective_scan_pallas(*args, block_d=8, block_s=16,
+                                       interpret=True)
         np.testing.assert_allclose(np.asarray(y1), np.asarray(y2), atol=1e-5)
         np.testing.assert_allclose(np.asarray(h1), np.asarray(h2), atol=1e-5)
 
@@ -345,13 +350,16 @@ class TestSelectiveScanKernel:
         A = jnp.asarray(-np.abs(rng.normal(size=(D, N))).astype(np.float32))
         Ds = jnp.asarray(rng.normal(size=(D,)).astype(np.float32))
         y_full, h_full = selective_scan_pallas(u, dt, Bc, Cc, A, Ds,
-                                               block_d=16, block_s=16)
+                                               block_d=16, block_s=16,
+                                               interpret=True)
         h = S // 2
         y_a, h_a = selective_scan_pallas(u[:, :h], dt[:, :h], Bc[:, :h],
-                                         Cc[:, :h], A, Ds, block_d=16, block_s=16)
+                                         Cc[:, :h], A, Ds, block_d=16, block_s=16,
+                                         interpret=True)
         y_b, h_b = selective_scan_pallas(u[:, h:], dt[:, h:], Bc[:, h:],
                                          Cc[:, h:], A, Ds, h0=h_a,
-                                         block_d=16, block_s=16)
+                                         block_d=16, block_s=16,
+                                         interpret=True)
         np.testing.assert_allclose(
             np.asarray(jnp.concatenate([y_a, y_b], axis=1)),
             np.asarray(y_full), atol=1e-5)

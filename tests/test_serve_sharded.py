@@ -22,6 +22,7 @@ import numpy as np
 import pytest
 
 from repro import configs
+from repro.dist import sharding as shd
 from repro.models import lm
 from repro.serve.scheduler import Request, Scheduler
 
@@ -120,7 +121,7 @@ def test_tp_knob_validation():
     params = lm.init(jax.random.PRNGKey(0), cfg)
     with pytest.raises(ValueError, match="not both"):
         Scheduler(cfg, params, tp=1,
-                  mesh=jax.make_mesh((1,), ("model",)))
+                  mesh=shd.make_mesh((1,), ("model",)))
     with pytest.raises(ValueError, match="exceeds"):
         Scheduler(cfg, params, tp=jax.device_count() + 1)
     with pytest.raises(ValueError, match=">= 1"):
@@ -137,7 +138,7 @@ def test_serving_param_rules_output_dims_only():
 
     cfg = configs.get_smoke_config("deepseek-v3-671b")  # MLA + MoE + MTP
     shapes = jax.eval_shape(lambda k: lm.init(k, cfg), jax.random.PRNGKey(0))
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = shd.make_mesh((1,), ("model",))
     tree = shd.serve_param_sharding_tree(shapes, mesh)
     assert len(jax.tree.leaves(tree, is_leaf=lambda x: hasattr(x, "spec"))) \
         == len(jax.tree.leaves(shapes))
@@ -172,7 +173,7 @@ def test_repl_act_noop_outside_exact_context():
 
     x = jnp.ones((4, 4))
     assert shd.repl_act(x) is x                      # no context
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = shd.make_mesh((1,), ("model",))
     with shd.use_mesh(mesh):                         # training ctx: not exact
         assert shd.repl_act(x) is x
     with shd.use_mesh(shd.serving_context(mesh)):

@@ -174,7 +174,7 @@ from repro import configs
 from repro.models import lm
 from repro.launch.specs import concrete_batch
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = shd.make_mesh((4, 2), ("data", "model"))
 cfg = configs.get_smoke_config("qwen2.5-3b")
 
 with shd.use_mesh(mesh):
@@ -194,13 +194,12 @@ loss_ref, _ = jax.jit(lambda p, b: lm.loss_fn(p, b, cfg))(params_r, batch_r)
 print(json.dumps({"sharded": loss_sharded, "ref": float(loss_ref)}))
 
 # compressed ring all-reduce numerics on 8 devices
-from repro.dist.compat import shard_map
 from repro.optim.grad_compress import ring_allreduce_int8
 x = np.random.default_rng(0).normal(size=(8, 1000)).astype(np.float32)
-ring_mesh = jax.make_mesh((8,), ("d",))
+ring_mesh = shd.make_mesh((8,), ("d",))
 def body(v):
     return ring_allreduce_int8(v[0], "d", 8)[None]
-out = jax.jit(shard_map(body, mesh=ring_mesh, in_specs=P("d"),
+out = jax.jit(jax.shard_map(body, mesh=ring_mesh, in_specs=P("d"),
                         out_specs=P("d"), check_vma=False))(x)
 got = np.asarray(out)[0]
 want = x.sum(0)
