@@ -168,7 +168,19 @@ def _ranks_jit(F, v, use_pallas: bool):
 
 
 def _final_ranks(F, v, cfg: NSGA2Config) -> np.ndarray:
-    return np.asarray(_ranks_jit(jnp.asarray(F), jnp.asarray(v), cfg.use_pallas))
+    with jax.profiler.TraceAnnotation("repro.nsga2.final_ranks"):
+        return np.asarray(_ranks_jit(jnp.asarray(F), jnp.asarray(v), cfg.use_pallas))
+
+
+def _dedup(archives) -> List[np.ndarray]:
+    """Each archive's distinct genomes, sorted (``np.unique`` by row)."""
+    archives = [np.asarray(a).reshape(-1, N_GENES) for a in archives]
+    with jax.profiler.TraceAnnotation(
+        "repro.nsga2.dedup", rows_in=sum(len(a) for a in archives)
+    ) as span:
+        arches = [np.unique(a, axis=0) for a in archives]
+        span.set_metadata(rows_out=sum(len(a) for a in arches))
+    return arches
 
 
 @jax.jit
@@ -209,25 +221,26 @@ def _extract_result(
     if deduped:
         arch = np.asarray(archive).reshape(-1, N_GENES)
     else:
-        arch = np.unique(np.asarray(archive).reshape(-1, N_GENES), axis=0)
-    arch_p, n = scen_mod.pad_to_bucket(arch, bucket)
-    # row() slices back to numpy scalars — transfer explicitly.
-    # device_put (not jnp.asarray): converting a host scalar's dtype
-    # routes through convert_element_type, an *implicit* transfer that
-    # jax.transfer_guard("disallow") rejects.
-    row = jax.tree.map(jax.device_put, row)
-    aF, av, mask = jax.tree.map(
-        lambda a: np.asarray(a)[:n],
-        _archive_front_jit(row, jnp.asarray(arch_p)),
-    )
-    return NSGA2Result(
-        genes=np.asarray(pop),
-        objectives=np.asarray(F),
-        violation=np.asarray(v),
-        ranks=np.asarray(ranks),
-        front_genes=arch[mask],
-        front_objectives=np.asarray(aF)[mask],
-    )
+        (arch,) = _dedup([archive])
+    with jax.profiler.TraceAnnotation("repro.nsga2.archive_front"):
+        arch_p, n = scen_mod.pad_to_bucket(arch, bucket)
+        # row() slices back to numpy scalars — transfer explicitly.
+        # device_put (not jnp.asarray): converting a host scalar's dtype
+        # routes through convert_element_type, an *implicit* transfer that
+        # jax.transfer_guard("disallow") rejects.
+        row = jax.tree.map(jax.device_put, row)
+        aF, av, mask = jax.tree.map(
+            lambda a: np.asarray(a)[:n],
+            _archive_front_jit(row, jnp.asarray(arch_p)),
+        )
+        return NSGA2Result(
+            genes=np.asarray(pop),
+            objectives=np.asarray(F),
+            violation=np.asarray(v),
+            ranks=np.asarray(ranks),
+            front_genes=arch[mask],
+            front_objectives=np.asarray(aF)[mask],
+        )
 
 
 def _seed_key(seed: int):
@@ -246,36 +259,55 @@ def run_batched(
 
     Each scenario uses the same RNG stream as a standalone
     :func:`run`/:func:`run_static` call with the same config, so the
-    batched fronts match the sequential per-scenario path exactly."""
+    batched fronts match the sequential per-scenario path exactly.
+
+    Under ``jax.profiler`` the call records nested ``repro.nsga2.*``
+    spans (``docs/dse.md``, "Tracing a DSE call"); the outer one carries
+    ``programs``, the jitted programs the call launched."""
     S = len(table)
-    key = _seed_key(cfg.seed)
-    keys = jnp.broadcast_to(key, (S,) + key.shape)
-    # Tables are built with numpy leaves; transfer them explicitly so
-    # the jit call itself stays clean under jax.transfer_guard (the
-    # transfers lint replays this path under "disallow").
-    table = jax.tree.map(jax.device_put, table)
-    out = _run_batched_jit(table, cfg, keys)
-    # Extraction below is host-side (np.unique, per-scenario slicing):
-    # pull the batch to host ONCE.  Indexing the device arrays per
-    # scenario instead would implicitly transfer each index scalar —
-    # the transfers lint runs this path under a disallow guard.
-    pops, F, v, archives = (np.asarray(x) for x in out)
-    # Dedup every scenario's archive first, then extract all fronts
-    # through ONE padded shape: S scenarios share a single
-    # ``_archive_front_jit`` compile instead of one per distinct size.
-    arches = [
-        np.unique(np.asarray(archives[i]).reshape(-1, N_GENES), axis=0)
-        for i in range(S)
-    ]
-    bucket = scen_mod._bucket(max(a.shape[0] for a in arches))
-    return [
-        _extract_result(
-            table.row(i), pops[i], F[i], v[i],
-            _final_ranks(F[i], v[i], cfg), arches[i],
-            bucket=bucket, deduped=True,
-        )
-        for i in range(S)
-    ]
+    with jax.profiler.TraceAnnotation(
+        "repro.nsga2.run_batched",
+        scenarios=S, pop=cfg.pop_size, generations=cfg.generations,
+    ) as call:
+        seed = np.int64(cfg.seed)
+        with jax.profiler.TraceAnnotation(
+            "repro.nsga2.upload",
+            bytes=seed.nbytes + sum(a.nbytes for a in jax.tree.leaves(table)),
+        ):
+            key = _seed_key(seed)
+            keys = jnp.broadcast_to(key, (S,) + key.shape)
+            programs = 2  # the seed's threefry and the broadcast, each eager
+            # Tables are built with numpy leaves; transfer them explicitly so
+            # the jit call itself stays clean under jax.transfer_guard (the
+            # transfers lint replays this path under "disallow").
+            table = jax.tree.map(jax.device_put, table)
+        with jax.profiler.TraceAnnotation("repro.nsga2.dispatch"):
+            out = _run_batched_jit(table, cfg, keys)
+            programs += 1
+        # Extraction below is host-side (np.unique, per-scenario slicing):
+        # pull the batch to host ONCE.  Indexing the device arrays per
+        # scenario instead would implicitly transfer each index scalar —
+        # the transfers lint runs this path under a disallow guard.
+        with jax.profiler.TraceAnnotation(
+            "repro.nsga2.wait", bytes=sum(x.nbytes for x in out)
+        ):
+            pops, F, v, archives = (np.asarray(x) for x in out)
+        # Dedup every scenario's archive first, then extract all fronts
+        # through ONE padded shape: S scenarios share a single
+        # ``_archive_front_jit`` compile instead of one per distinct size.
+        arches = _dedup(archives)
+        with jax.profiler.TraceAnnotation("repro.nsga2.fronts"):
+            bucket = scen_mod._bucket(max(a.shape[0] for a in arches))
+            results = []
+            for i in range(S):
+                ranks = _final_ranks(F[i], v[i], cfg)
+                results.append(_extract_result(
+                    table.row(i), pops[i], F[i], v[i], ranks, arches[i],
+                    bucket=bucket, deduped=True,
+                ))
+                programs += 2  # _ranks_jit and _archive_front_jit
+        call.set_metadata(programs=programs)
+    return results
 
 
 def run(space, cfg: NSGA2Config = NSGA2Config()) -> NSGA2Result:
