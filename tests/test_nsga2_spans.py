@@ -110,6 +110,8 @@ def test_dedup_rows(traced):
     stats = _one(spans, "repro.nsga2.dedup")[3]
     assert stats["rows_in"] == S * (CFG.generations + 1) * CFG.pop_size
     assert sum(len(r.front_genes) for r in results) <= stats["rows_out"] <= stats["rows_in"]
+    # the genes lie in the DSE's small bounds: every row goes through the packed key
+    assert stats["packed"] == stats["rows_in"]
 
 
 def test_programs_counts_the_executions(traced):
