@@ -2,17 +2,25 @@
 ``bench/traffic/``; this module turns it and a seed into the requests of
 one measured window.
 
-Every seed gets the same multiset of sizes, gaps and think times, drawn
-at the quantiles (i + 1/2)/n of each stated distribution, in an order
-the seed permutes; token ids are drawn from the seed.  So two seeds
-offer the same work in another order, and the spread between seeds is
-the system's, not the sampler's.
+Every seed gets the same arrival times and the same multiset of sizes
+and think times; token ids are drawn from the seed.  The arrivals are one
+path of a Poisson process, bursts and lulls included, that every run
+shares: the gaps at the quantiles of the exponential distribution in one
+fixed order.  The sizes and think times, drawn at the quantiles
+(i + 1/2)/n of each stated distribution, go to the arrivals in an order
+the seed permutes (``order``): every run of ``BLOCK`` consecutive
+arrivals holds one value from each of ``BLOCK`` bands of the
+distribution, so a seed cannot put the longest requests all early or all
+late in the window.  So two seeds offer the same work at the same
+moments, and the spread between seeds is the system's, not the
+sampler's.
 
 A mix holds:
 
 * ``arrival``: ``{"process": "poisson", "rate_per_s": r}``.  The window
   of ``seconds`` holds n = round(r * seconds) arrivals, with gaps at the
-  exponential quantiles scaled so that n + 1 gaps span the window.
+  exponential quantiles, in the order ``ARRIVAL_PATH`` and n fix, scaled
+  so that n + 1 gaps span the window.
 * Either ``prompt_tokens`` and ``output_tokens`` (one request per
   arrival), or ``session``: one session per arrival, holding
   ``document_tokens`` shared by its ``asks`` requests, each ``document +
@@ -37,6 +45,8 @@ from typing import List, Optional
 import numpy as np
 
 _STD_NORMAL = NormalDist()
+ARRIVAL_PATH = 0xA77     # seeds the one order of the arrival gaps that every run shares
+BLOCK = 8                # places per run of ``order``: one rank from each of 8 bands
 
 
 def quantile(dist: dict, q: float) -> float:
@@ -52,12 +62,23 @@ def quantile(dist: dict, q: float) -> float:
     return min(max(x, dist.get("min", -math.inf)), dist.get("max", math.inf))
 
 
+def order(n: int, rng, block: int = BLOCK) -> np.ndarray:
+    """A seeded order of the ranks 0..n-1 in which every run of ``block``
+    consecutive places holds one rank from each of ``block`` bands (the
+    lowest ranks, the next, ... as ``np.array_split`` cuts them), and the
+    last, shorter run one from each of the larger bands.  Which rank of a
+    band goes to which run, and the order inside a run, the seed decides."""
+    bands = [b[rng.permutation(b.size)] for b in np.array_split(np.arange(n), block)]
+    runs = [np.array([b[i] for b in bands if i < b.size]) for i in range(-(-n // block))]
+    return np.concatenate([r[rng.permutation(r.size)] for r in runs])
+
+
 def draws(dist: dict, n: int, rng, integer: bool = True) -> np.ndarray:
     """n values at the distribution's quantiles, in a seeded order."""
     xs = np.array([quantile(dist, (i + 0.5) / n) for i in range(n)])
     if integer:
         xs = np.rint(xs).astype(np.int64)
-    return xs[rng.permutation(n)]
+    return xs[order(n, rng)]
 
 
 @dataclasses.dataclass
@@ -84,7 +105,7 @@ def generate(mix: dict, seed: int, seconds: float, vocab: int,
         raise ValueError(f"unknown arrival process {mix['arrival']['process']!r}")
     n = max(1, round(rate * seconds))
     gaps = np.array([-math.log1p(-(i + 0.5) / (n + 1)) for i in range(n + 1)])
-    gaps = gaps[rng.permutation(n + 1)]
+    gaps = gaps[np.random.default_rng([n, ARRIVAL_PATH]).permutation(n + 1)]
     due = np.cumsum(gaps)[:n] * seconds / gaps.sum()
 
     greedy = mix.get("greedy_share", 1.0)

@@ -138,6 +138,16 @@ def per_layer_metrics(bench: dict, cell: str, ctx: dict) -> dict:
     return out
 
 
+def end_to_end_metrics(bench: dict, cell: str, values: dict) -> dict:
+    """Of a run's end-to-end readings, those ``BENCHMARK.json`` lists for
+    this cell (or lists for every cell): a reading the benchmark does not
+    bound for the cell, such as a session mix's TTFT tail, is left out of
+    the result line."""
+    listed = {m["name"] for m in bench["end_to_end"]
+              if cell in m.get("workloads", [cell])}
+    return {k: v for k, v in values.items() if k in listed}
+
+
 def emit(result: dict, compared: dict) -> None:
     """The contract's last lines: each compared number beside its limit on
     standard error, and the result as the last line of standard output,

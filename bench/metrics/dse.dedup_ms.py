@@ -1,6 +1,7 @@
 """Host time deduplicating the scenarios' archives (the
-``repro.nsga2.dedup`` span, ``np.unique`` by row) per ``run_batched``
-call in the traced span, in ms."""
+``repro.nsga2.dedup`` span: one dedup of every archive's rows through a
+packed integer key, its ``packed`` stat the rows that took that path)
+per ``run_batched`` call in the traced span, in ms."""
 from bench import spans
 
 

@@ -249,6 +249,7 @@ def run(bench: dict, cell: dict, conf: dict, mix: dict, seed: int,
         tracer.start()
     win.run()
     unfinished = win.finish(GRACE_S)
+    drain_s = time.perf_counter() - win.end
     session.stop()
     if tracer is not None:
         tracer.join()
@@ -262,12 +263,14 @@ def run(bench: dict, cell: dict, conf: dict, mix: dict, seed: int,
     lag = [r.called - r.due for r in recs]
     blocked = [r.accepted - r.called for r in recs]
     print(f"requests {len(recs)} unfinished {unfinished} tokens_in_window {in_window} "
-          f"ttft_p50_ms {_pct(ttft, 50) * 1e3:.1f} itl_p50_ms {_pct(itl, 50) * 1e3:.2f} "
+          f"ttft_p50_ms {_pct(ttft, 50) * 1e3:.1f} ttft_p95_ms {_pct(ttft, 95) * 1e3:.1f} "
+          f"itl_p50_ms {_pct(itl, 50) * 1e3:.2f} "
           f"generator_lag_p50_ms {_pct(lag, 50) * 1e3:.2f} "
           f"generator_lag_max_ms {max(lag, default=0) * 1e3:.1f} "
           f"submit_blocked_p50_ms {_pct(blocked, 50) * 1e3:.2f} "
           f"submit_blocked_max_ms {max(blocked, default=0) * 1e3:.1f} "
-          f"compiles_in_window {compiles_in_window}", file=sys.stderr, flush=True)
+          f"drain_s {drain_s:.1f} compiles_in_window {compiles_in_window}",
+          file=sys.stderr, flush=True)
     if compiles_in_window:
         raise RuntimeError(f"{compiles_in_window} compile events inside the window")
 
@@ -285,19 +288,21 @@ def run(bench: dict, cell: dict, conf: dict, mix: dict, seed: int,
         result["device"].update(busy_s=reduced["busy_s"], window_s=reduced["window_s"])
         result["breakdown"] = reduced["breakdown"]
     else:
-        result["metrics"] = {
+        result["metrics"] = harness.end_to_end_metrics(bench, cell["name"], {
             "ttft_p95_ms": {"value": _pct(ttft, 95) * 1e3, "unit": "ms"},
             "itl_p95_ms": {"value": _pct(itl, 95) * 1e3, "unit": "ms"},
             "output_tokens_per_s": {"value": in_window / seconds, "unit": "tokens/s"},
             "setup_s": {"value": setup_s, "unit": "s"},
-        }
+        })
 
     # Free the program's state before the reference runs.
     del session, sched, win
     for r in recs:
         r.handle = None
     gc.collect()
+    t_check = time.perf_counter()
     compared = check(conf, recs, seed, unfinished)
+    print(f"check_s {time.perf_counter() - t_check:.1f}", file=sys.stderr, flush=True)
     result["correct"] = all(c["value"] <= c["limit"] for c in compared.values())
     return result, compared
 
@@ -326,7 +331,8 @@ def check(conf: dict, recs, seed: int, unfinished: int) -> dict:
         [np.asarray(r.tokens, np.int32) for r in picked]) if picked else []
     widest = max((float(g.max()) for g in gaps), default=float("inf"))
     print(f"checked {len(picked)} requests, {sum(len(g) for g in gaps)} served "
-          f"tokens", file=sys.stderr, flush=True)
+          f"tokens, {sum(1 for r in picked if r.hit)} of them prefilled over a cached "
+          f"prefix", file=sys.stderr, flush=True)
     return {
         "unfinished_requests": {"value": unfinished, "limit": 0},
         "wrong_length_streams": {"value": wrong_length, "limit": 0},

@@ -15,7 +15,8 @@ from repro.core.explorer import _normalize_scenarios
 from repro.core.scenario import ScenarioTable
 
 DATA = pathlib.Path(__file__).resolve().parent / "data"
-BENCH = {"per_layer": []}
+BENCH = {"per_layer": [], "end_to_end": [
+    {"name": n} for n in ("ttft_p95_ms", "itl_p95_ms", "output_tokens_per_s", "setup_s")]}
 
 
 def _load(name):
@@ -38,6 +39,20 @@ def test_sound_serving_run_is_correct():
     assert result["attempted"] > 10 and result["failed"] == 0
     assert set(result["metrics"]) == {"ttft_p95_ms", "itl_p95_ms",
                                       "output_tokens_per_s", "setup_s"}
+
+
+def test_sound_docqa_run_is_correct(monkeypatch):
+    """The session mix through the traced path: asks after a session's
+    first find its document in the prefix cache, and the run is
+    correct."""
+    monkeypatch.setattr(harness, "peaks", lambda kind: {})
+    bench = {**BENCH, "per_layer": [{"name": "prefix_hit_share", "unit": "%"}]}
+    result, compared = serve.run(bench, {"name": "smoke"}, _load("qwen-smoke"),
+                                 _load("docqa-smoke"), 2 ** 31 + 13, 6.0, True,
+                                 harness.device_info())
+    assert result["correct"], compared
+    assert result["attempted"] > 10 and result["failed"] == 0
+    assert result["metrics"]["prefix_hit_share"]["value"] > 0
 
 
 def test_altered_token_fails(monkeypatch):

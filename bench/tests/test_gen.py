@@ -1,6 +1,8 @@
 """The traffic generator: reproducible from the seed, within the mix's
-stated ranges, and the same multiset of sizes for every seed."""
+stated ranges, the same multiset of sizes and the same arrival path for
+every seed, and sizes spread evenly over the window."""
 import json
+import math
 import pathlib
 
 import numpy as np
@@ -73,3 +75,54 @@ def test_docqa_sessions_share_their_document():
             shared = n if differ.size == 0 else differ[0]
             assert shared >= s["document_tokens"]["min"]
         assert asks[0].due_s is not None and all(a.due_s is None for a in asks[1:])
+
+
+@pytest.mark.parametrize("name", ["chat", "docqa"])
+def test_every_seed_shares_one_poisson_arrival_path(name):
+    """The arrival times do not depend on the seed, and their gaps are the
+    exponential quantiles, scaled to the window, in some order."""
+    mix = _mix(name)
+    seconds = 51
+    runs = [gen.generate(mix, seed, seconds, 1000) for seed in (1, 2, 2 ** 31 + 7)]
+    dues = [np.array([s[0].due_s for s in sessions]) for sessions in runs]
+    for due in dues[1:]:
+        assert np.array_equal(due, dues[0])
+    n = len(dues[0])
+    assert n == round(mix["arrival"]["rate_per_s"] * seconds)
+    q = np.array([-math.log1p(-(i + 0.5) / (n + 1)) for i in range(n + 1)])
+    gaps = np.diff(np.concatenate([[0.0], dues[0]]))
+    expected = np.sort(q * seconds / q.sum())
+    # n of the n + 1 gaps lie before the last arrival: each is one of the quantiles.
+    assert np.isin(np.round(gaps, 9), np.round(expected, 9)).all()
+    assert not (np.diff(gaps) > 0).all() and not (np.diff(gaps) < 0).all()
+
+
+@pytest.mark.parametrize("n", [1, 7, 8, 61, 143])
+def test_order_puts_one_rank_of_each_band_in_every_run(n):
+    """``order`` is a permutation, and each run of ``BLOCK`` places holds
+    ranks from as many bands: no seed puts a band's values together."""
+    band = np.empty(n, int)
+    for i, b in enumerate(np.array_split(np.arange(n), gen.BLOCK)):
+        band[b] = i
+    for seed in range(5):
+        o = gen.order(n, np.random.default_rng(seed))
+        assert sorted(o) == list(range(n))
+        for start in range(0, n, gen.BLOCK):
+            run = band[o[start:start + gen.BLOCK]]
+            assert len(set(run)) == len(run)
+
+
+def test_order_evens_out_the_work_between_stretches():
+    """Output tokens offered in each quarter of a window of 143 chat
+    requests vary between seeds far less than under a plain permutation
+    (``block=1``)."""
+    mix = _mix("chat")
+    n = 143
+    xs = np.array([gen.quantile(mix["output_tokens"], (i + 0.5) / n) for i in range(n)])
+
+    def quarter_std(block):
+        sums = [[q.sum() for q in np.array_split(
+            xs[gen.order(n, np.random.default_rng(seed), block)], 4)] for seed in range(40)]
+        return np.std(sums, axis=0).mean()
+
+    assert quarter_std(gen.BLOCK) < 0.6 * quarter_std(1)
