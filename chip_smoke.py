@@ -23,6 +23,16 @@ Everything runs in this one process, which holds the chip.  Phases:
   shape of the served model) must equal an exact int32 matmul;
   ``ops.dcim_fp_matmul`` at that shape prints its error against an f32
   matmul at full precision.
+* **four_chips** (``--four-chips`` alone) — mistral-nemo-12b at its
+  published widths serves through ``Scheduler`` at tp=4 in the serving
+  layout: each chip holds a quarter of every matrix and of the
+  vocabulary (``wo`` and ``w_down`` row-parallel, each ending in one
+  all-reduce), 6.12e9 parameter bytes, which the scheduler's
+  ``param_bytes_per_chip`` counter reads from the placed shards.  Then,
+  cut to ``compare_layers`` layers, tp=4 against tp=1 in lockstep
+  within ``logit_tolerance``: the all-reduce adds in another order than
+  one chip's dot, so tp is not bitwise, and a greedy token may differ
+  only at a near-tie.
 
 Each phase prints one JSON line (device, compile and run seconds,
 ``peak_bytes_in_use``, what was compared); the last line is
@@ -421,21 +431,14 @@ def dcim_phase(M: int = 256, K: int = 2048, N: int = 11008, *,
 
 
 # ------------------------------ four chips ------------------------------------
-def per_device_bytes(tree) -> dict:
-    out = {}
-    for leaf in jax.tree.leaves(tree):
-        for s in leaf.addressable_shards:
-            out[str(s.device.id)] = out.get(str(s.device.id), 0) + s.data.nbytes
-    return out
-
-
 def four_chip_phase(cfg, *, tp: int = 4, compare_layers: int = 8,
                     max_slots: int = 4, max_len: int = 512,
                     page_size: int = 16, prompt_lens=(200, 160, 120, 240),
                     prefix_len: int = 128, n_tokens: int = 16,
                     buckets=(128, 256), seed: int = 0) -> dict:
     """``Scheduler(tp=N)`` with a model no single chip holds, then the
-    same program at ``compare_layers`` layers against tp=1."""
+    same program at ``compare_layers`` layers against tp=1, in lockstep
+    within ``logit_tolerance``."""
     rec = {"phase": "four_chips", "model": cfg.name, "tp": tp}
     mesh = shd.make_mesh((tp,), ("model",))
     kw = dict(max_slots=max_slots, max_len=max_len, page_size=page_size,
@@ -445,8 +448,8 @@ def four_chip_phase(cfg, *, tp: int = 4, compare_layers: int = 8,
     with timed(rec, "init_full"):
         params = jax.block_until_ready(
             init_params(cfg, jax.random.PRNGKey(seed), mesh))
-    rec["param_bytes_per_device_full"] = per_device_bytes(params)
     sched = Scheduler(cfg, params, mesh=mesh, **kw)
+    rec["param_bytes_per_chip_full"] = sched.param_bytes_per_chip
     with timed(rec, "serve_full"):
         res = sched.serve(reqs)
     check(all(r.generated.size == n_tokens for r in res),

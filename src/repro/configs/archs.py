@@ -210,8 +210,10 @@ def mistral_nemo_12b(**ov) -> LMConfig:
 
 
 def mistral_nemo_12b_smoke() -> LMConfig:
+    # heads * head_dim = 64 != d_model = 80, as the published 32 * 128 =
+    # 4096 != 5120; 4 KV heads split over a 4-way serving mesh.
     return mistral_nemo_12b(
-        n_layers=2, d_model=64, n_heads=4, n_kv=2, d_ff=128, head_dim=16,
+        n_layers=2, d_model=80, n_heads=8, n_kv=4, d_ff=224, head_dim=8,
         vocab_size=512,
         param_dtype="float32", compute_dtype="float32", remat=False,
         attn_chunk_q=8, attn_chunk_kv=8, loss_chunk=0,

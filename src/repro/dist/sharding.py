@@ -38,6 +38,7 @@ import threading
 from typing import Dict, Optional, Sequence, Tuple
 
 import jax
+import jax.numpy as jnp
 from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 Axes = Tuple[str, ...]
@@ -85,18 +86,19 @@ def default_rules(mesh: Mesh) -> Dict[str, Axes]:
 class MeshContext:
     """Resolves logical axis names against one physical mesh.
 
-    ``exact=True`` marks a *serving* context: the program must stay
-    bitwise-identical to its unsharded run, so :func:`repl_act` gathers
-    activations back to replicated before every contraction over a
-    sharded dim (all communication is all-gather — pure data movement).
-    Training contexts leave it ``False`` and :func:`repl_act` is a no-op.
+    ``serving=True`` marks a *serving* context (:func:`serving_context`):
+    :func:`repl_act` gathers an activation back to replicated,
+    :func:`take_rows` looks rows up in a row-sharded table, and the
+    row-parallel projections (``models.common.dense_row_parallel``) add
+    their partial sums.  Training contexts leave it ``False``; there
+    :func:`repl_act` is a no-op and GSPMD places every collective.
     """
 
     def __init__(self, mesh: Mesh, rules: Optional[Dict[str, Axes]] = None,
-                 exact: bool = False):
+                 serving: bool = False):
         self.mesh = mesh
         self.rules = dict(rules) if rules is not None else default_rules(mesh)
-        self.exact = bool(exact)
+        self.serving = bool(serving)
 
     # -- resolution -----------------------------------------------------------
     def _axis_size(self, axis: str) -> int:
@@ -204,24 +206,50 @@ def shard_act(x, logical_dims: LogicalDims):
 
 
 def repl_act(x):
-    """Gather ``x`` back to fully replicated under an ``exact`` (serving)
-    context; identity otherwise.
+    """Constrain ``x`` to fully replicated under a serving context;
+    identity otherwise.
 
-    Exact tensor parallelism never lets a *contracted* dim stay sharded:
-    a sharded contraction would finish with an all-reduce whose partial
-    sums associate differently than the single-device dot, breaking
-    bitwise identity.  Model code calls this immediately before every
-    contraction over a potentially-sharded dim (attention output
-    projection, FFN down projection, the MoE combine, the logits
-    consumed by sampling) so the only collective the partitioner can
-    emit there is an all-gather of the operand — exact data movement.
+    Model code calls it where every device needs the whole value: the
+    vocab-sharded logits that sampling reads (an all-gather), the MoE
+    combine over expert-sharded outputs, and the output of a row-parallel
+    projection, whose partial sums the constraint turns into one
+    all-reduce.
     """
     ctx = current()
-    if ctx is None or not ctx.exact:
+    if ctx is None or not ctx.serving:
         return x
     return jax.lax.with_sharding_constraint(
         x, NamedSharding(ctx.mesh, P())
     )
+
+
+def take_rows(table, ids):
+    """``table[ids]`` for an embedding table.  Under a serving context
+    whose ``vocab`` rule splits the table's rows over the mesh, each
+    device looks the ids up in the rows it holds, writes zeros for ids
+    outside them, and one all-reduce (named scope ``repro.tp.embed``)
+    adds the devices' rows: exact in any dtype, since one device gives
+    each row.  The table is never gathered whole."""
+    ctx = current()
+    axes = (ctx.axes_for("vocab", table.shape[0])
+            if ctx is not None and ctx.serving else None)
+    if not axes:
+        return table[ids]
+    axis = axes[0] if len(axes) == 1 else axes
+
+    def local(tab, idx):
+        n = tab.shape[0]
+        rel = idx - jax.lax.axis_index(axis) * n
+        held = (rel >= 0) & (rel < n)
+        rows = tab[jnp.where(held, rel, 0)]
+        rows = jnp.where(held[..., None], rows, jnp.zeros((), rows.dtype))
+        return jax.lax.psum(rows, axis)
+
+    with jax.named_scope("repro.tp.embed"):
+        return jax.shard_map(
+            local, mesh=ctx.mesh, in_specs=(P(axis), P()), out_specs=P(),
+            check_vma=False,
+        )(table, ids)
 
 
 # ------------------------------ parameter rules --------------------------------
@@ -302,47 +330,49 @@ def param_sharding_tree(shape_tree, mesh: Mesh, rules: Optional[Dict[str, Axes]]
     return jax.tree_util.tree_map_with_path(one, shape_tree)
 
 
-# ------------------------------ exact serving rules ----------------------------
-# Logical names a *serving* mesh resolves — only non-contracting output
-# dims.  ``tp``/``d_inner``/``batch``/``seq_sp``/``fsdp`` are deliberately
-# absent: every existing shard_act annotation that names them resolves to
-# replicated under a serving context, which is exactly what bitwise
-# identity with the single-device program requires (see SERVE_PARAM_RULES).
+# ------------------------------ serving rules ----------------------------------
+# Logical names a *serving* mesh resolves.  ``tp``/``d_inner``/``batch``/
+# ``seq_sp``/``fsdp`` are deliberately absent: every annotation that names
+# them resolves to replicated under a serving context (one replica's
+# batch is never split, and the mamba and MLA contractions stay whole).
 _SERVE_MODEL_LOGICAL = ("heads", "kv_heads", "ff", "experts", "vocab")
 
 
 def serve_rules(mesh: Mesh) -> Dict[str, Axes]:
-    """Logical->physical rules for an exact tensor/expert-parallel
-    serving mesh (axis name ``"model"``)."""
+    """Logical->physical rules for a tensor/expert-parallel serving mesh
+    (axis name ``"model"``)."""
     names = tuple(mesh.axis_names)
     model = tuple(a for a in _MODEL_AXES if a in names)
     return {logical: model for logical in _SERVE_MODEL_LOGICAL}
 
 
 def serving_context(mesh: Mesh) -> MeshContext:
-    """The exact-serving :class:`MeshContext` for ``mesh``."""
-    return MeshContext(mesh, rules=serve_rules(mesh), exact=True)
+    """The serving :class:`MeshContext` for ``mesh``."""
+    return MeshContext(mesh, rules=serve_rules(mesh), serving=True)
 
 
 # Serving parameter layout (ordered, first match wins; unmatched ->
-# replicate).  Only *output* dims shard, so every matmul contracts over a
-# replicated dim and each output element is the same full-length dot
-# product the single-device program computes — no partial-sum
-# all-reduces anywhere, hence bitwise-exact decode.  Deliberately
-# replicated (their outputs feed a contraction the activation side
-# re-gathers anyway, or sharding them would break exactness):
-#   * ``wo`` / dense ``w_down`` / MoE bank ``w_down`` output d_model;
-#   * ``embed`` (token gather + tied-unembedding families);
-#   * MLA factors: the absorbed attend contracts kv_lora_rank, so MLA
-#     attention stays replicated — MLA+MoE families (deepseek) get their
-#     parallelism from the expert banks;
-#   * all mamba parameters (x_proj/out_proj contract d_inner).
+# replicate): Megatron-style tensor parallelism.  Column-parallel
+# ``wq``/``wk``/``wv`` and ``w_up``/``w_gate`` split their outputs (heads,
+# ff columns); row-parallel ``wo`` and dense ``w_down`` split their input
+# rows the same way, so the activation between the two never leaves its
+# device, and each row-parallel product ends in one all-reduce of partial
+# sums (``models.common.dense_row_parallel``).  The embedding and the LM
+# head split the vocabulary (``take_rows``; the logits are gathered for
+# sampling).  Replicated, as their contractions need whole operands:
+#   * MLA factors (the absorbed attend contracts kv_lora_rank);
+#   * all mamba parameters (x_proj/out_proj contract d_inner);
+# MoE expert banks split the experts (expert parallelism).  Norms and
+# biases replicate.
 SERVE_PARAM_RULES: Tuple[Tuple[str, Tuple[Optional[str], ...]], ...] = (
     (r"(?:^|/)wq/w$", (None, "heads")),
     (r"(?:^|/)(?:wk|wv)/w$", (None, "kv_heads")),
+    (r"(?:^|/)wo/w$", ("heads", None)),
     (r"(?:^|/)(?:w_up|w_gate)/w$", (None, "ff")),
+    (r"(?:^|/)w_down/w$", ("ff", None)),
     (r"(?:^|/)(?:w_gate|w_up)$", ("experts", None, "ff")),
     (r"(?:^|/)w_down$", ("experts", None, None)),
+    (r"(?:^|/)embed/w$", ("vocab", None)),
     (r"(?:^|/)head/w$", (None, "vocab")),
 )
 _SERVE_PARAM_RULES = tuple(
@@ -364,7 +394,7 @@ def serve_logical_for_path(path: str, ndim: int) -> Tuple[Optional[str], ...]:
 
 
 def serve_param_sharding_tree(shape_tree, mesh: Mesh):
-    """``NamedSharding`` per parameter for exact serving on ``mesh``."""
+    """``NamedSharding`` per parameter for serving on ``mesh``."""
     ctx = serving_context(mesh)
 
     def one(path, leaf):
@@ -375,10 +405,10 @@ def serve_param_sharding_tree(shape_tree, mesh: Mesh):
 
 
 # Paged-pool leaves by key.  GQA K/V pages are (groups, n_pages, page,
-# n_kv, hd) — sharded over kv heads, the one big serving buffer that
-# scales down per-device.  MLA latent pages contract kv_lora_rank in the
-# absorbed attend and SSM states feed elementwise recurrences whose
-# surrounding projections contract d_inner: both replicate.
+# n_kv, hd) — sharded over kv heads, as the attention that reads them.
+# MLA latent pages contract kv_lora_rank in the absorbed attend and SSM
+# states feed elementwise recurrences whose surrounding projections
+# contract d_inner: both replicate.
 _SERVE_POOL_LOGICAL: Dict[str, Tuple[Optional[str], ...]] = {
     "k": (None, None, None, "kv_heads", None),
     "v": (None, None, None, "kv_heads", None),
@@ -398,7 +428,7 @@ def _pool_logical(path, ndim: int) -> Tuple[Optional[str], ...]:
 
 
 def serve_pool_sharding_tree(shape_tree, mesh: Mesh):
-    """``NamedSharding`` per paged-pool leaf for exact serving."""
+    """``NamedSharding`` per paged-pool leaf for serving."""
     ctx = serving_context(mesh)
 
     def one(path, leaf):
@@ -415,9 +445,9 @@ def constrain_pool(pool):
     in whatever layout it liked best internally; the session rebinds the
     result as the next call's input, whose sharding then differs from
     the traced one — a recompile per step, and a different layout again
-    the step after.  No-op outside an exact serving context."""
+    the step after.  No-op outside a serving context."""
     ctx = current()
-    if ctx is None or not ctx.exact:
+    if ctx is None or not ctx.serving:
         return pool
 
     def one(path, leaf):

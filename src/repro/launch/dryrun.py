@@ -195,15 +195,15 @@ def build_decode_cell(cfg: LMConfig, shape, mesh):
 def build_decode_tp_cell(cfg: LMConfig, shape, mesh, page_size: int = 16):
     """The tensor-parallel PAGED serving decode program — the program
     ``serve.Scheduler(tp=N)`` actually runs — on a 1-D ``("model",)``
-    mesh: params laid out by the output-dim-only serving rules, K/V
+    mesh: params laid out by the serving rules (column- and
+    row-parallel projections, vocab-split embedding and head), K/V
     pages head-sharded, block tables / positions / inputs replicated
     (they are host-driven state), and the returned pool pinned back to
     its input layout so donation aliases without a relayout.
 
     Must be traced under ``shd.serving_context(mesh)`` (run_cell does
-    this) so the in-model ``repl_act`` gathers are live — they are what
-    keeps every contraction full-length and the tokens bitwise equal to
-    single-device serving."""
+    this) so the in-model serving collectives are live: the row-parallel
+    all-reduces, the embedding lookup and the logits gather."""
     B, S = shape.global_batch, shape.seq_len
     n_pages = 1 + B * (S // page_size)
     params_shapes = jax.eval_shape(partial(lm.init, cfg=cfg), jax.random.PRNGKey(0))

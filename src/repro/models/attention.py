@@ -21,7 +21,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.dist.sharding import repl_act, shard_act
+from repro.dist.sharding import shard_act
 from repro.kernels import ops as kops
 from . import common
 from .common import apply_mrope, apply_rope, dense, dense_init
@@ -227,6 +227,12 @@ def _gather_pages(pages: jnp.ndarray, block_table: jnp.ndarray) -> jnp.ndarray:
     return g.reshape(g.shape[0], -1, *g.shape[3:])
 
 
+def _out_proj(p, o):
+    """The output projection ``wo`` of the (B, S, heads * hd) attention
+    output, row-parallel over the heads under a serving mesh."""
+    return common.dense_row_parallel(p["wo"], o, "heads", "repro.tp.attn_out")
+
+
 # ================================= GQA =======================================
 def gqa_init(key, cfg, dtype):
     hd = cfg.head_dim or cfg.d_model // cfg.n_heads
@@ -274,10 +280,7 @@ def gqa_apply_train(p, x, cfg, position_ids=None):
     )
     B, S = x.shape[:2]
     o = shard_act(o, ("batch", None, "heads", None))
-    # Exact serving gathers heads before the wo contraction (repl_act is
-    # a no-op outside an exact mesh context) — same at every wo below.
-    o = repl_act(o)
-    return dense(p["wo"], o.reshape(B, S, -1).astype(x.dtype)), (k, v)
+    return _out_proj(p, o.reshape(B, S, -1).astype(x.dtype)), (k, v)
 
 
 def gqa_apply_decode(p, x, cfg, cache, pos, position_ids=None):
@@ -297,7 +300,7 @@ def gqa_apply_decode(p, x, cfg, cache, pos, position_ids=None):
         k_cache = jax.lax.dynamic_update_slice(cache["k"], k.astype(cache["k"].dtype), (0, pos, 0, 0))
         v_cache = jax.lax.dynamic_update_slice(cache["v"], v.astype(cache["v"].dtype), (0, pos, 0, 0))
     o = decode_attention(q, k_cache, v_cache, pos)
-    y = dense(p["wo"], repl_act(o).reshape(B, 1, -1).astype(x.dtype))
+    y = _out_proj(p, o.reshape(B, 1, -1).astype(x.dtype))
     return y, {"k": k_cache, "v": v_cache}
 
 
@@ -323,7 +326,7 @@ def gqa_apply_decode_paged(p, x, cfg, cache, block_table, pos):
     o = kops.paged_decode_gqa(
         q, k_pages, v_pages, block_table, pos, backend=cfg.attn_backend
     )
-    y = dense(p["wo"], repl_act(o).reshape(B, 1, -1).astype(x.dtype))
+    y = _out_proj(p, o.reshape(B, 1, -1).astype(x.dtype))
     return y, {"k": k_pages, "v": v_pages}
 
 
@@ -356,7 +359,7 @@ def gqa_apply_prefix(p, x, cfg, cache, block_table, ctx_len, wr_pg, wr_rw,
     )
     k_pages = cache["k"].at[wr_pg, wr_rw].set(k.astype(cache["k"].dtype))
     v_pages = cache["v"].at[wr_pg, wr_rw].set(v.astype(cache["v"].dtype))
-    y = dense(p["wo"], repl_act(o).reshape(B, T, -1).astype(x.dtype))
+    y = _out_proj(p, o.reshape(B, T, -1).astype(x.dtype))
     return y, {"k": k_pages, "v": v_pages}
 
 
@@ -438,7 +441,7 @@ def mla_apply_train(p, x, cfg, position_ids=None):
     o = flash_attention(
         q, k, v, causal=True, q_chunk=cfg.attn_chunk_q, kv_chunk=cfg.attn_chunk_kv
     )
-    y = dense(p["wo"], repl_act(o).reshape(B, S, -1).astype(x.dtype))
+    y = _out_proj(p, o.reshape(B, S, -1).astype(x.dtype))
     return y, (c_kv, k_rope)
 
 
@@ -517,7 +520,7 @@ def mla_apply_decode(p, x, cfg, cache, pos):
             cache["k_rope"], kr_new.astype(cache["k_rope"].dtype), (0, pos, 0)
         )
     o = _mla_absorbed_attend(p, cfg, q_nope, q_rope, ckv, krope, pos)
-    y = dense(p["wo"], repl_act(o).reshape(B, 1, -1).astype(x.dtype))
+    y = _out_proj(p, o.reshape(B, 1, -1).astype(x.dtype))
     return y, {"c_kv": ckv, "k_rope": krope}
 
 
@@ -542,7 +545,7 @@ def mla_apply_decode_paged(p, x, cfg, cache, block_table, pos):
     )
     with common.precision_island("attn"):
         o = jnp.einsum("bqhr,rhd->bqhd", ctx, w_uv.astype(jnp.float32))
-    y = dense(p["wo"], repl_act(o).reshape(B, 1, -1).astype(x.dtype))
+    y = _out_proj(p, o.reshape(B, 1, -1).astype(x.dtype))
     return y, {"c_kv": ckv_pages, "k_rope": kr_pages}
 
 
@@ -595,5 +598,5 @@ def mla_apply_prefix(p, x, cfg, cache, block_table, ctx_len, wr_pg, wr_rw,
 
     ckv_pages = cache["c_kv"].at[wr_pg, wr_rw].set(c_kv.astype(cache["c_kv"].dtype))
     kr_pages = cache["k_rope"].at[wr_pg, wr_rw].set(k_rope.astype(cache["k_rope"].dtype))
-    y = dense(p["wo"], repl_act(o).reshape(B, T, -1).astype(x.dtype))
+    y = _out_proj(p, o.reshape(B, T, -1).astype(x.dtype))
     return y, {"c_kv": ckv_pages, "k_rope": kr_pages}

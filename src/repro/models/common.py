@@ -12,7 +12,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.dist.sharding import repl_act, shard_act
+from repro.dist.sharding import current, repl_act, shard_act
 
 
 def precision_island(name: str):
@@ -65,7 +65,10 @@ def set_mvm_impl(fn):
     return prev
 
 
-def dense(p, x):
+def dense(p, x, reduce=None):
+    """``x @ p["w"] (+ p["b"])`` accumulated in float32 and cast to
+    ``x``'s dtype.  ``reduce``, where given, maps the cast product before
+    the bias is added (the all-reduce of :func:`dense_row_parallel`)."""
     with precision_island("dense"):
         # Cast only on a real mismatch: a no-op convert_element_type in
         # the jaxpr would read as a spurious cast site to the lint.
@@ -75,10 +78,32 @@ def dense(p, x):
             y = y.astype(x.dtype)
         else:
             y = _MVM_IMPL(x, w).astype(x.dtype)
+        if reduce is not None:
+            y = reduce(y)
         if "b" in p:
             b = p["b"] if p["b"].dtype == x.dtype else p["b"].astype(x.dtype)
             y = y + b
     return y
+
+
+def dense_row_parallel(p, x, logical: str, scope: str):
+    """``dense(p, x)`` for a row-parallel projection (``wo`` over the
+    heads, ``w_down`` over the ff columns).  Under a serving mesh the
+    contracted dim of ``x`` and the rows of ``p["w"]`` are both split by
+    ``logical``: each device multiplies what it holds, accumulating in
+    float32, casts its partial sum to the compute dtype, and one
+    all-reduce in the named scope ``scope`` adds the partial sums.  (The
+    TPU compiler turns an all-reduce of float32 partial sums followed by
+    the cast into this same compute-dtype all-reduce, which
+    ``tests/test_tpu_compile.py`` checks for v5e; casting first says so,
+    and halves the bytes on backends that would keep float32.)
+    Anywhere else it is ``dense``."""
+    ctx = current()
+    if ctx is None or not ctx.serving:
+        return dense(p, x)
+    with jax.named_scope(scope):
+        x = shard_act(x, (None,) * (x.ndim - 1) + (logical,))
+        return dense(p, x, reduce=repl_act)
 
 
 def last_valid_hidden(h, valid_len):
@@ -224,8 +249,7 @@ def ffn_apply(p, x, act: str):
     else:  # pragma: no cover
         raise ValueError(act)
     h = shard_act(h, ("batch", None, "ff"))
-    # Exact serving gathers the ff dim before the w_down contraction.
-    return dense(p["w_down"], repl_act(h))
+    return dense_row_parallel(p["w_down"], h, "ff", "repro.tp.ffn_down")
 
 
 def softmax_xent_chunked(

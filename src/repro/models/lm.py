@@ -23,7 +23,7 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.dist.sharding import repl_act, shard_act
+from repro.dist.sharding import repl_act, shard_act, take_rows
 from . import attention as attn
 from . import common, mamba as ssm, moe as moe_mod
 from .common import (
@@ -217,7 +217,7 @@ def embed_inputs(params, batch: Dict[str, Any], cfg: LMConfig, offset=0):
     if cfg.external_embed:
         x = batch["embeds"].astype(cfg.cdtype)
     else:
-        x = params["embed"]["w"].astype(cfg.cdtype)[batch["tokens"]]
+        x = take_rows(params["embed"]["w"].astype(cfg.cdtype), batch["tokens"])
     if cfg.pos == "sinusoidal":
         B, S = x.shape[:2]
         pos = jnp.arange(S)[None, :] + offset
@@ -235,9 +235,9 @@ def _head_logits(params, h, cfg: LMConfig):
             ).astype(h.dtype)
         else:
             logits = dense(params["head"], h)
-        # Exact serving gathers vocab-sharded logits so argmax/categorical
-        # sampling runs fully replicated (identical reduction order and RNG
-        # bits on every device); no-op outside an exact mesh context.
+        # A serving mesh gathers the vocab-sharded logits so argmax and
+        # categorical sampling run replicated (the same reduction order
+        # and RNG bits on every device); no-op outside a serving context.
         return repl_act(logits)
 
 
@@ -440,9 +440,8 @@ def init_paged_pool(cfg: LMConfig, n_slots: int, n_pages: int, page_size: int,
 
     With ``mesh`` (a tensor-parallel serving mesh, axis ``"model"``) the
     K/V page leaves are laid out head-sharded via
-    ``dist.sharding.serve_pool_sharding_tree`` — the one serving buffer
-    whose per-device footprint shrinks with tp — while MLA latent pages
-    and SSM states replicate (their contractions must stay exact)."""
+    ``dist.sharding.serve_pool_sharding_tree``, while MLA latent pages
+    and SSM states replicate (their contractions need whole operands)."""
     period = cfg.scan_period()
     groups = cfg.n_layers // period
     cdt = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}[cfg.cache_dtype]
@@ -464,12 +463,17 @@ def init_paged_pool(cfg: LMConfig, n_slots: int, n_pages: int, page_size: int,
             lambda a: jnp.zeros((groups,) + a.shape, a.dtype), c
         )
 
-    pool = tuple(one(cfg.mixer_kind(pos)) for pos in range(period))
-    if mesh is not None:
-        from repro.dist.sharding import serve_pool_sharding_tree
+    def make():
+        return tuple(one(cfg.mixer_kind(pos)) for pos in range(period))
 
-        pool = jax.device_put(pool, serve_pool_sharding_tree(pool, mesh))
-    return pool
+    if mesh is None:
+        return make()
+    from repro.dist.sharding import serve_pool_sharding_tree
+
+    # Made where it lives: a pool larger than one chip is never whole on
+    # any device, as it would be if made and then placed.
+    layout = serve_pool_sharding_tree(jax.eval_shape(make), mesh)
+    return jax.jit(make, out_shardings=layout)()
 
 
 def decode_step_paged(params, inputs, pos, pool, block_tables, cfg: LMConfig):

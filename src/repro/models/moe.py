@@ -133,10 +133,9 @@ def moe_apply(p, x, cfg, training: bool = True):
         h = h * jnp.einsum("gtd,edf->egtf", xg, w_up.astype(xg.dtype))
         h = shard_act(h, ("experts", "batch", None, "ff"))
         ye = jnp.einsum("egtf,efd->egtd", h, w_down.astype(xg.dtype))
-        # Exact serving gathers the expert dim before the combine: the
-        # weighted sum over experts must associate exactly as it does on
-        # one device (top_k >= 3 sums are order-sensitive, and sharded
-        # zeros for unrouted experts flip -0.0 signs).
+        # A serving mesh gathers the expert dim before the combine, so
+        # the weighted sum over experts associates as it does on one
+        # device (top_k >= 3 sums are order-sensitive).
         ye = repl_act(ye)
         y = jnp.einsum("gte,egtd->gtd", gates.astype(ye.dtype), ye)
         y = y.reshape(B, S, D)

@@ -104,7 +104,7 @@ import contextlib
 import dataclasses
 import threading
 import time
-from collections import deque
+from collections import Counter, deque
 from functools import partial
 from typing import Callable, Dict, Iterator, List, Optional, Sequence, Set, Tuple, Union
 
@@ -1451,6 +1451,15 @@ def init_params(cfg: LMConfig, key, mesh=None):
     )(key)
 
 
+def _bytes_per_device(tree) -> int:
+    """Bytes of ``tree``'s arrays on the device that holds the most."""
+    per = Counter()
+    for leaf in jax.tree.leaves(tree):
+        for shard in leaf.addressable_shards:
+            per[shard.device] += shard.data.nbytes
+    return max(per.values(), default=0)
+
+
 class Scheduler:
     """Continuous-batching engine over a paged KV-cache pool.
 
@@ -1506,12 +1515,12 @@ class Scheduler:
             cfg = dataclasses.replace(cfg, attn_backend=attn_backend).validate()
         # Tensor/expert-parallel serving mesh.  Like attn_backend this is
         # pure plumbing with zero call-site churn: params and the paged
-        # pool are laid out by the exact serving rules
+        # pool are laid out by the serving rules
         # (dist.sharding.serve_param_sharding_tree /
         # serve_pool_sharding_tree) and every trace/call runs inside
         # _numerics()'s use_mesh, so the SAME jitted programs partition
-        # over the mesh while greedy tokens stay bitwise-identical to the
-        # single-device run (all communication is all-gather).
+        # over the mesh (row-parallel projections end in an all-reduce;
+        # tokens agree with one device's within rounding, not bitwise).
         if tp is not None:
             if mesh is not None:
                 raise ValueError("pass either mesh= or tp=, not both")
@@ -1531,6 +1540,9 @@ class Scheduler:
             )
         self.cfg = cfg
         self.params = params
+        # Counter: parameter bytes the fullest device holds, read from
+        # the placed shards.
+        self.param_bytes_per_chip = _bytes_per_device(params)
         self.max_slots = int(max_slots)
         self.max_len = int(max_len)
         self.eos_id = eos_id
@@ -1869,10 +1881,11 @@ def _build_serve_contract() -> Built:
                 "('model',) mesh at a smoke config: the sharded pool "
                 "donation must still alias, a replayed trace must stay "
                 "within the single-device compile budget (sharding adds "
-                "no programs), and the partitioned decode HLO must move "
-                "data only — per-device all-gather bytes under budget, "
-                "all-to-all forbidden for this non-MoE family (exact "
-                "serving has no partial-sum collectives to reshuffle)",
+                "no programs), and the partitioned programs' collectives "
+                "must stay within budget — each all-reduce at most one "
+                "(tokens, d_model) partial sum of a row-parallel "
+                "projection, the logits all-gather bounded, all-to-all "
+                "forbidden for this non-MoE family",
 )
 def _build_serve_tp_contract() -> Built:
     from repro.analysis.jaxpr_tools import (
@@ -1941,12 +1954,18 @@ def _build_serve_tp_contract() -> Built:
         live_budget={"decode": 1, "prefill": len(sched._prefills)},
     )
 
-    # Per-device all-gather budget: the biggest replicated-gather in one
-    # decode step is the logits gather, vocab * n_slots * 4B per device
-    # — everything else (heads/ff re-gathers) is smaller at this config.
-    # Order-of-magnitude headroom, but far below a partial-sum-sized
-    # rewrite; all-to-all at 0 is the real teeth for a non-MoE family.
-    budget = {"all-gather": 1 << 20, "all-to-all": 0}
+    # Per-device budgets.  The largest all-reduce of a program adds the
+    # partial sums of one row-parallel projection (wo, w_down) over the
+    # call's tokens, in the compute dtype: at most tokens * d_model * 4
+    # bytes, as is the embedding's all-reduce of the looked-up rows.
+    # Anything larger is a weight or a pool being reduced.  The biggest all-gather
+    # is the logits gather for sampling, vocab * n_slots * 4 B; an order
+    # of magnitude of headroom, far below a gathered weight.  All-to-all
+    # at 0 is the teeth for a non-MoE family.
+    def budget(tokens):
+        return {"all-reduce": tokens * cfg.d_model * 4,
+                "all-gather": 1 << 20, "all-to-all": 0}
+
     S = sched.max_slots
     decode_args = (
         sched.params, session.pool, jnp.asarray(session.cur),
@@ -1957,7 +1976,7 @@ def _build_serve_tp_contract() -> Built:
     with shd.use_mesh(sched.mesh_ctx):
         units = [compile_unit(
             "decode_tp", sched._decode, decode_args, donate_argnums=(1,),
-            shard_divisors=(1, n_dev), collective_budget=budget,
+            shard_divisors=(1, n_dev), collective_budget=budget(S),
         )]
         if sched._prefills:
             bucket, width = sorted(
@@ -1977,7 +1996,7 @@ def _build_serve_tp_contract() -> Built:
                 f"prefill_tp[{bucket},{width}]",
                 sched._prefill_jit((bucket, width)), prefill_args,
                 donate_argnums=(1,), shard_divisors=(1, n_dev),
-                collective_budget=budget,
+                collective_budget=budget(width * bucket),
             ))
         decode_jaxpr = jax.make_jaxpr(
             partial(_decode_paged_fn, cfg=cfg)

@@ -76,6 +76,13 @@ print(json.dumps(rec))
 def test_four_chip_phase_on_virtual_devices(run_in_8dev_subprocess):
     (rec,) = run_in_8dev_subprocess(_FOUR_CHIP_SNIPPET, n_devices=4)
     assert rec["served_streams_identical"] == "4/4"
-    assert len(rec["param_bytes_per_device_full"]) == 4
+    # The counter reads one chip's shards: a quarter of the smoke model's
+    # float32 matrices and vocabulary, plus its norms whole.
+    cfg = configs.get_smoke_config("mistral-nemo-12b")
+    d, ff, V, L = cfg.d_model, cfg.d_ff, cfg.vocab_size, cfg.n_layers
+    q, kv = cfg.n_heads * cfg.hd, cfg.n_kv * cfg.hd
+    matrices = L * (2 * d * q + 2 * d * kv + 3 * d * ff) + 2 * V * d
+    norms = (2 * L + 1) * d
+    assert rec["param_bytes_per_chip_full"] == 4 * (matrices // 4 + norms)
     stats = rec["lockstep_tp_vs_tp1"]
     assert stats["max_logit_diff"] <= stats["max_tolerance"]

@@ -161,7 +161,7 @@ def _fake_ctx(sizes, rules):
         def __init__(self):
             self.mesh = mesh
             self.rules = dict(rules)
-            self.exact = False
+            self.serving = False
 
         def _axis_size(self, axis):
             return sizes[axis]

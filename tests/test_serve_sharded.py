@@ -1,15 +1,15 @@
-"""Tensor/expert-parallel sharded serving: bitwise exactness vs the
-single-device scheduler.
+"""Tensor/expert-parallel sharded serving.
 
-The headline invariant: a ``Scheduler(tp=N)`` on the forced-8-device
-host platform produces greedy tokens BITWISE-IDENTICAL to the
-single-device scheduler for every served architecture family, at
-tp=2/4/8, with the compile budget (one decode program + one prefill per
-(bucket, width) key) unchanged by sharding.  Exactness is by
-construction — the serving rules shard only non-contracting output dims
-and ``repl_act`` gathers before every contraction, so the partitioned
-program computes every dot product at full length in the same order —
-and these tests are the enforcement.
+``Scheduler(tp=N)`` serves the same paged programs over a 1-D
+``("model",)`` mesh laid out by the serving rules: column-parallel
+Q/K/V and up/gate projections, row-parallel ``wo`` and dense ``w_down``
+whose partial sums one all-reduce adds, embedding and LM head split by
+vocabulary, MoE banks by experts.  The all-reduce adds in a different
+order than one device's dot, so tp is not bitwise equal to tp=1: the
+tests hold it to tp=1's logits within a stated tolerance, and to bitwise
+equality where the program fixes the order itself — one tp program under
+different arrivals and batching.  ``tests/test_serve_tp_reference.py``
+holds tp=4 to the plain float32 reference.
 
 The tp=1 test runs in tier-1 on the ordinary single-device host: it
 drives the whole mesh code path (param/pool device_put, ``use_mesh``
@@ -33,13 +33,25 @@ SERVED_ARCHS = [
     "moonshot-v1-16b-a3b",
 ]
 
-# One subprocess per family: reference serve + tp=2/4/8 re-serves of the
-# same trace, token lists compared bitwise in the child, budget asserted
-# from the live jit cache sizes.  ``%(arch)s`` is the only template hole.
-_EXACTNESS_SNIPPET = r"""
+# Logits at tp=N against tp=1: the smoke configs compute in float32, and
+# tp reorders sums (the row-parallel all-reduce over d_model / N partial
+# products, the vocab-split embedding adds zeros).  A few float32 ulps
+# (2^-24) of reordering through two layers stays far inside 2^-14 of the
+# largest logit; a real divergence (a lost shard, a doubled partial sum)
+# moves logits by O(1).
+TP_LOGIT_TOL_REL = 2.0 ** -14
+
+# One subprocess per family: teacher-forced paged decode logits at
+# tp=2/4/8 against tp=1 from an empty pool, then one tp Scheduler serving
+# the same requests under two arrival orders (different admission,
+# batching and chunking), token lists compared bitwise, and the compile
+# budget asserted from the live jit cache sizes.  ``%(arch)s`` is the
+# only template hole.
+_TP_SNIPPET = r"""
 import dataclasses, json
-import jax, numpy as np
+import jax, jax.numpy as jnp, numpy as np
 from repro import configs
+from repro.dist import sharding as shd
 from repro.models import lm
 from repro.serve.scheduler import Request, Scheduler
 
@@ -49,25 +61,60 @@ cfg = configs.get_smoke_config("%(arch)s")
 # architecture permits (prefix reuse, preemption, chunked prefill).
 cfg = dataclasses.replace(cfg, cache_dtype="float32")
 params = lm.init(jax.random.PRNGKey(0), cfg)
+B, T, PAGE, MAX_LEN = 3, 12, 8, 32
+nb = MAX_LEN // PAGE
+bt = jnp.arange(1, 1 + B * nb, dtype=jnp.int32).reshape(B, nb)
+toks = np.random.default_rng(1).integers(1, 64, (T, B)).astype(np.int32)
+decode = jax.jit(lambda p, pool, t, pos: lm.decode_step_paged(
+    p, {"tokens": t[:, None]}, pos, pool, bt, cfg))
+
+
+def lockstep(mesh):
+    p = params if mesh is None else jax.device_put(
+        params, shd.serve_param_sharding_tree(params, mesh))
+    pool = lm.init_paged_pool(cfg, B, B * nb + 1, PAGE, mesh=mesh)
+    out = []
+    for t in range(T):
+        pos = jnp.full((B,), t, jnp.int32)
+        if mesh is None:
+            lg, pool = decode(p, pool, jnp.asarray(toks[t]), pos)
+        else:
+            with shd.use_mesh(shd.serving_context(mesh)):
+                lg, pool = decode(p, pool, jnp.asarray(toks[t]), pos)
+        out.append(np.asarray(lg[:, -1], np.float32))
+    return np.stack(out)
+
 
 rng = np.random.default_rng(0)
-reqs = [Request(prompt=rng.integers(1, 64, p).astype(np.int32),
-                n_tokens=t, rid=i, arrival=a)
-        for i, (p, t, a) in enumerate(
-            [(3, 4, 0), (9, 3, 0), (17, 5, 1), (5, 2, 1), (12, 3, 2)])]
-kw = dict(max_slots=3, max_len=32, page_size=8, prefill_chunk=8)
+shape = [(3, 4, 0), (9, 3, 0), (17, 5, 1), (5, 2, 1), (12, 3, 2)]
+prompts = [rng.integers(1, 64, p).astype(np.int32) for p, _, _ in shape]
+reqs = [Request(prompt=pr, n_tokens=t, rid=i, arrival=a)
+        for i, (pr, (_, t, a)) in enumerate(zip(prompts, shape))]
+# The same requests, all due at once and submitted in reverse.
+burst = [Request(prompt=pr, n_tokens=t, rid=i, arrival=0)
+         for i, (pr, (_, t, _)) in reversed(list(enumerate(zip(prompts, shape))))]
+kw = dict(max_slots=3, max_len=MAX_LEN, page_size=PAGE, prefill_chunk=8)
 
+# The single-device reference serves both orders too, so its compile
+# count is the budget the tp program must match.
 ref_sched = Scheduler(cfg, params, **kw)
-ref = [list(map(int, r.tokens)) for r in ref_sched.serve(reqs)]
+ref_sched.serve(reqs)
+ref_sched.serve(burst)
 ref_counts = ref_sched.compile_counts()
+want = lockstep(None)
 
 for tp in (2, 4, 8):
+    mesh = shd.make_mesh((tp,), ("model",))
+    got = lockstep(mesh)
     s = Scheduler(cfg, params, tp=tp, **kw)
-    got = [list(map(int, r.tokens)) for r in s.serve(reqs)]
+    a = {r.rid: list(map(int, r.tokens)) for r in s.serve(reqs)}
+    b = {r.rid: list(map(int, r.tokens)) for r in s.serve(burst)}
     c = s.compile_counts()
     print(json.dumps({
         "tp": tp,
-        "bitwise": got == ref,
+        "max_logit_diff": float(np.abs(got - want).max()),
+        "max_abs_logit": float(np.abs(want).max()),
+        "bitwise_across_scheduling": a == b,
         "decode_compiles": c["decode"],
         "prefill_compiles": sum(c["prefill"].values()),
         "prefill_keys": len(c["prefill"]),
@@ -81,17 +128,18 @@ for tp in (2, 4, 8):
 @pytest.mark.slow
 @pytest.mark.parametrize("arch", SERVED_ARCHS)
 def test_tp_serving_bitwise_exact_8dev(arch, run_in_8dev_subprocess):
-    records = run_in_8dev_subprocess(
-        _EXACTNESS_SNIPPET % {"arch": arch}, timeout=600
-    )
+    """tp=2/4/8 agree with tp=1 within ``TP_LOGIT_TOL_REL``, and one tp
+    program serves bitwise the same tokens under different arrivals."""
+    records = run_in_8dev_subprocess(_TP_SNIPPET % {"arch": arch}, timeout=600)
     assert [r["tp"] for r in records] == [2, 4, 8]
     for r in records:
-        assert r["bitwise"], f"{arch} tp={r['tp']} tokens diverged: {r}"
+        assert r["max_logit_diff"] <= TP_LOGIT_TOL_REL * r["max_abs_logit"], (arch, r)
+        assert r["bitwise_across_scheduling"], f"{arch} tp={r['tp']}: {r}"
         # Compile budget: sharding must not add programs — exactly one
-        # decode, one prefill per (bucket, width) key actually used,
-        # and the same total as the single-device reference.
+        # decode, one prefill per (bucket, width) key actually used.
         assert r["decode_compiles"] == 1, r
         assert r["prefill_compiles"] == r["prefill_keys"], r
+        # and the same total as the single-device reference.
         assert r["total"] == r["ref_total"], r
 
 
@@ -113,7 +161,7 @@ def test_tp1_mesh_serving_exact_single_device():
     got = [list(map(int, r.tokens)) for r in sched.serve(reqs)]
     assert got == ref
     assert sched.compile_counts()["decode"] == 1
-    assert sched.mesh is not None and sched.mesh_ctx.exact
+    assert sched.mesh is not None and sched.mesh_ctx.serving
 
 
 def test_tp_knob_validation():
@@ -129,13 +177,13 @@ def test_tp_knob_validation():
 
 
 def test_serving_param_rules_output_dims_only():
-    """Every serving param rule shards only output dims: resolving the
-    full smoke param tree must leave each matmul's contracting dim
-    replicated (spec entry None at dim 0 of 2-dim leaves)."""
-    from jax.sharding import PartitionSpec as P
-
-    from repro.dist import sharding as shd
-
+    """The serving layout is row-parallel where it should be: each
+    column-parallel projection splits its output dim, and the
+    row-parallel projection that consumes it splits its input rows by the
+    same logical name, so the activation between them stays on its
+    device; the embedding and LM head split the vocabulary; MLA factors
+    and mamba parameters stay whole (their contractions need whole
+    operands), and MoE banks split the experts."""
     cfg = configs.get_smoke_config("deepseek-v3-671b")  # MLA + MoE + MTP
     shapes = jax.eval_shape(lambda k: lm.init(k, cfg), jax.random.PRNGKey(0))
     mesh = shd.make_mesh((1,), ("model",))
@@ -146,24 +194,30 @@ def test_serving_param_rules_output_dims_only():
     for path, leaf in jax.tree_util.tree_flatten_with_path(shapes)[0]:
         p = shd._path_str(path)
         logical = shd.serve_logical_for_path(p, len(leaf.shape))
-        # contraction safety: dense /w leaves never shard their input dim
-        if p.endswith("/w") and len(leaf.shape) >= 2:
-            assert logical[-2] is None, (p, logical)
-        # MLA factors, mamba and wo/w_down/embed stay fully replicated
-        for frag in ("wo/w", "q_a/w", "q_b/w", "kv_a/w", "kv_b/w",
-                     "embed/w", "in_proj/w", "out_proj/w", "x_proj/w"):
-            if p.endswith(frag):
-                assert logical == (None,) * len(leaf.shape), (p, logical)
-    # spot-check the sharded ones
-    assert shd.serve_logical_for_path("blocks/0/mixer/wq/w", 2) == \
-        (None, "heads")
-    assert shd.serve_logical_for_path("blocks/0/ffn/w_gate", 3) == \
-        ("experts", None, "ff")
-    assert shd.serve_logical_for_path("blocks/0/ffn/w_down", 3) == \
-        ("experts", None, None)
-    assert shd.serve_logical_for_path("head/w", 2) == (None, "vocab")
-    assert shd.serve_logical_for_path("blocks/ffn/w_up", 4) == \
-        (None, "experts", None, "ff")
+        if p.endswith(("q_a/w", "q_b/w", "kv_a/w", "kv_b/w", "in_proj/w",
+                       "out_proj/w", "x_proj/w", "mtp/proj/w")):
+            assert logical == (None,) * len(leaf.shape), (p, logical)
+        if p.endswith(("wo/w", "w_down/w")):
+            assert logical[-2] in ("heads", "ff") and logical[-1] is None, (p, logical)
+        if p.endswith(("wq/w", "w_up/w", "w_gate/w", "head/w")):
+            assert logical[-2] is None and logical[-1] is not None, (p, logical)
+    # column-parallel producer and row-parallel consumer share the split
+    rule = shd.serve_logical_for_path
+    assert rule("blocks/0/mixer/wq/w", 2) == (None, "heads")
+    assert rule("blocks/0/mixer/wo/w", 2) == ("heads", None)
+    assert rule("blocks/0/mixer/wk/w", 2) == (None, "kv_heads")
+    assert rule("blocks/0/ffn/w_up/w", 2) == (None, "ff")
+    assert rule("blocks/0/ffn/w_down/w", 2) == ("ff", None)
+    assert rule("blocks/0/ffn/shared/w_down/w", 3) == (None, "ff", None)
+    assert rule("embed/w", 2) == ("vocab", None)
+    assert rule("head/w", 2) == (None, "vocab")
+    # MoE banks: expert parallelism, as before
+    assert rule("blocks/0/ffn/w_gate", 3) == ("experts", None, "ff")
+    assert rule("blocks/0/ffn/w_down", 3) == ("experts", None, None)
+    assert rule("blocks/ffn/w_up", 4) == (None, "experts", None, "ff")
+    # norms and biases replicate
+    assert rule("blocks/0/ln1/scale", 1) == (None,)
+    assert rule("blocks/0/mixer/wq/b", 1) == (None,)
 
 
 def test_repl_act_noop_outside_exact_context():
@@ -174,9 +228,9 @@ def test_repl_act_noop_outside_exact_context():
     x = jnp.ones((4, 4))
     assert shd.repl_act(x) is x                      # no context
     mesh = shd.make_mesh((1,), ("model",))
-    with shd.use_mesh(mesh):                         # training ctx: not exact
+    with shd.use_mesh(mesh):                         # training ctx
         assert shd.repl_act(x) is x
     with shd.use_mesh(shd.serving_context(mesh)):
-        y = shd.repl_act(x)                          # exact ctx: constrained
+        y = shd.repl_act(x)                          # serving ctx: constrained
         assert y.shape == x.shape
         np.testing.assert_array_equal(np.asarray(y), np.asarray(x))

@@ -7,30 +7,37 @@ The topology is described inside a module fixture, never while a module
 is imported: only one process at a time may load the TPU library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from repro import configs
+from repro.dist import sharding as shd
 from repro.kernels import dcim_mvm, paged_attention, pareto_rank, selective_scan
+from repro.models import common
 
 # The serving shape of chip_smoke.py's serve phase.
 SLOTS, MAX_LEN, PAGE = 4, 1024, 16
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e_2x2():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
 
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:  # no TPU compiler in this installation
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e_2x2):
+    return SingleDeviceSharding(v5e_2x2.devices[0])
 
 
 def _compile(fn, *shapes):
@@ -92,3 +99,30 @@ def test_selective_scan_jamba(one_chip):
     _compile(selective_scan.selective_scan_pallas,
              f32(B, S, D), f32(B, S, D), f32(B, S, N), f32(B, S, N),
              f32(D, N), f32(D))
+
+
+@pytest.mark.parametrize("tokens", [64, 1536])
+def test_row_parallel_partial_sums_all_reduce_in_bf16(v5e_2x2, tokens):
+    """``wo`` of mistral-nemo-12b at tp=4, for a decode step's 64 rows and
+    a 1536-token prefill: an all-reduce of float32 partial sums followed
+    by the cast to bf16 compiles to one bf16 all-reduce, the same one
+    ``common.dense_row_parallel`` asks for by casting first."""
+    mesh = shd.make_mesh((4,), ("model",), devices=v5e_2x2.devices[:4])
+    x = jax.ShapeDtypeStruct((tokens, 4096), jnp.bfloat16,
+                             sharding=NamedSharding(mesh, P(None, "model")))
+    w = jax.ShapeDtypeStruct((4096, 5120), jnp.bfloat16,
+                             sharding=NamedSharding(mesh, P("model", None)))
+
+    def float32_sums(x, w):
+        y = jnp.matmul(x, w, preferred_element_type=jnp.float32)
+        y = jax.lax.with_sharding_constraint(y, NamedSharding(mesh, P()))
+        return y.astype(jnp.bfloat16)
+
+    def program(x, w):
+        with shd.use_mesh(shd.serving_context(mesh)):
+            return common.dense_row_parallel({"w": w}, x, "heads", "repro.tp.attn_out")
+
+    for fn in (float32_sums, program):
+        hlo = jax.jit(fn).lower(x, w).compile().as_text()
+        dtypes = re.findall(r"= (\w+)\[[^\]]*\]\S* all-reduce(?:-start)?\(", hlo)
+        assert dtypes == ["bf16"], (fn.__name__, dtypes)
