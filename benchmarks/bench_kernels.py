@@ -93,20 +93,20 @@ def run(smoke: bool) -> dict:
     for B, Hk, G, hd, page, nb in _PAGED_DECODE[smoke]:
         n_pages = 1 + B * nb
         S = nb * page
-        kp = jnp.asarray(rng.standard_normal((n_pages, page, Hk, hd)),
+        kp = jnp.asarray(rng.standard_normal((1, n_pages, page, Hk, hd)),
                          jnp.bfloat16)
-        vp = jnp.asarray(rng.standard_normal((n_pages, page, Hk, hd)),
+        vp = jnp.asarray(rng.standard_normal((1, n_pages, page, Hk, hd)),
                          jnp.bfloat16)
         bt = jnp.asarray(
             rng.permutation(np.arange(1, n_pages))[: B * nb].reshape(B, nb),
             jnp.int32)
         q = jnp.asarray(rng.standard_normal((B, 1, Hk * G, hd)), jnp.float32)
         pos = jnp.asarray(rng.integers(S // 2, S, B), jnp.int32)
-        us_k = time_fn(lambda *a: ops.paged_decode_gqa(*a), q, kp, vp, bt, pos)
-        us_r = time_fn(lambda *a: ops.paged_decode_gqa(*a, backend="xla"),
+        us_k = time_fn(lambda *a: ops.paged_decode_gqa(*a, 0), q, kp, vp, bt, pos)
+        us_r = time_fn(lambda *a: ops.paged_decode_gqa(*a, 0, backend="xla"),
                        q, kp, vp, bt, pos)
         us_i = time_fn(
-            lambda *a: ops.paged_decode_gqa(*a, backend="pallas_interpret"),
+            lambda *a: ops.paged_decode_gqa(*a, 0, backend="pallas_interpret"),
             q, kp, vp, bt, pos)
         toks = round(B / us_k * 1e6, 1)
         kv_bytes = 2 * B * S * Hk * hd * kp.dtype.itemsize   # K+V read

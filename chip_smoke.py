@@ -249,14 +249,14 @@ def attention_kernels_vs_xla(cfg, *, slots: int, max_len: int,
     nb = max_len // page_size
     n_pages = slots * nb + 1
     bf = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.bfloat16)
-    k_pages, v_pages = bf(n_pages, page_size, Hk, hd), bf(n_pages, page_size, Hk, hd)
+    k_pages, v_pages = bf(1, n_pages, page_size, Hk, hd), bf(1, n_pages, page_size, Hk, hd)
     table = jnp.asarray(rng.permutation(np.arange(1, n_pages))[:slots * nb]
                         .reshape(slots, nb), jnp.int32)
     pos = jnp.asarray(rng.integers(0, max_len, slots), jnp.int32)
     q = bf(slots, 1, H, hd) * 8
     tol = 2.0 ** -7 * float(jnp.abs(v_pages.astype(jnp.float32)).max())
     out = {}
-    got, want = (ops.paged_decode_gqa(q, k_pages, v_pages, table, pos, backend=b)
+    got, want = (ops.paged_decode_gqa(q, k_pages, v_pages, table, pos, 0, backend=b)
                  for b in (kernel, "xla"))
     out["decode_max_diff"] = float(jnp.abs(got - want).max())
 

@@ -91,9 +91,11 @@ def _per_device_heads(kernel, args, head_axes, n_kv):
     )(*args)
 
 
-def paged_decode_gqa(q, k_pages, v_pages, block_table, pos, backend=None):
+def paged_decode_gqa(q, k_pages, v_pages, block_table, pos, layer,
+                     backend=None):
     """Paged GQA decode attention: (B, 1, H, hd) q against the slot's
-    pages.  XLA: gather a contiguous per-slot view, run
+    pages in slab ``layer`` of the stacked (groups, n_pages, page, Hk,
+    hd) pool.  XLA: gather a contiguous per-slot view, run
     ``decode_attention``.  PALLAS: the fused block-table kernel."""
     b = resolve_attn_backend(backend)
     if b is AttnBackend.XLA:
@@ -101,8 +103,8 @@ def paged_decode_gqa(q, k_pages, v_pages, block_table, pos, backend=None):
 
         return _attn.decode_attention(
             q,
-            _attn._gather_pages(k_pages, block_table),
-            _attn._gather_pages(v_pages, block_table),
+            _attn._gather_pages(k_pages, block_table, layer),
+            _attn._gather_pages(v_pages, block_table, layer),
             pos,
         )
     kernel = functools.partial(
@@ -110,31 +112,31 @@ def paged_decode_gqa(q, k_pages, v_pages, block_table, pos, backend=None):
         interpret=b is AttnBackend.PALLAS_INTERPRET,
     )
     return _per_device_heads(
-        kernel, (q, k_pages, v_pages, block_table, pos),
-        (2, 2, 2, None, None), k_pages.shape[2],
+        kernel, (q, k_pages, v_pages, block_table, pos, layer),
+        (2, 3, 3, None, None, None), k_pages.shape[3],
     )
 
 
 def paged_decode_mla(q_abs, q_rope, ckv_pages, krope_pages, block_table,
-                     pos, scale: float, backend=None):
-    """Paged absorbed-MLA decode in the compressed c_kv space; returns
-    the (B, 1, H, r) f32 context (``w_uv`` up-projection stays with the
-    caller)."""
+                     pos, layer, scale: float, backend=None):
+    """Paged absorbed-MLA decode in the compressed c_kv space, over slab
+    ``layer`` of the stacked pages; returns the (B, 1, H, r) f32 context
+    (``w_uv`` up-projection stays with the caller)."""
     b = resolve_attn_backend(backend)
     if b is AttnBackend.XLA:
         from repro.models import attention as _attn
 
         return _attn.mla_attend_core(
             q_abs, q_rope,
-            _attn._gather_pages(ckv_pages, block_table),
-            _attn._gather_pages(krope_pages, block_table),
+            _attn._gather_pages(ckv_pages, block_table, layer),
+            _attn._gather_pages(krope_pages, block_table, layer),
             pos, scale,
         )
     kernel = functools.partial(
         _pa.paged_decode_mla_pallas, scale=scale,
         interpret=b is AttnBackend.PALLAS_INTERPRET,
     )
-    args = (q_abs, q_rope, ckv_pages, krope_pages, block_table, pos)
+    args = (q_abs, q_rope, ckv_pages, krope_pages, block_table, pos, layer)
     return _per_device_heads(kernel, args, (None,) * len(args), None)
 
 
@@ -426,19 +428,20 @@ def _build_kernels_contract() -> Built:
     # scalar-prefetch refs, which the grid-coverage evaluator cannot
     # replay — that surfaces as a lint *warning*, by design.
     Bd, Hk, G, hd = 2, 2, 4, 128
-    page, nb = 8, 3
+    page, nb, groups = 8, 3, 2
     traces.append(PallasTrace(
         "paged_attention.paged_decode_gqa_pallas",
         jax.make_jaxpr(
-            lambda q, kp, vp, bt, ps: _pa.paged_decode_gqa_pallas(
-                q, kp, vp, bt, ps, interpret=True
+            lambda q, kp, vp, bt, ps, ly: _pa.paged_decode_gqa_pallas(
+                q, kp, vp, bt, ps, ly, interpret=True
             )
         )(
             jnp.zeros((Bd, 1, Hk * G, hd), jnp.float32),
-            jnp.zeros((nb * Bd + 1, page, Hk, hd), jnp.bfloat16),
-            jnp.zeros((nb * Bd + 1, page, Hk, hd), jnp.bfloat16),
+            jnp.zeros((groups, nb * Bd + 1, page, Hk, hd), jnp.bfloat16),
+            jnp.zeros((groups, nb * Bd + 1, page, Hk, hd), jnp.bfloat16),
             jnp.zeros((Bd, nb), jnp.int32),
             jnp.zeros((Bd,), jnp.int32),
+            jnp.int32(1),
         ),
         interpret_fallback=fallback,
     ))

@@ -1,11 +1,14 @@
 """Fused paged-attention Pallas kernels (vLLM-style).
 
 Decode: one kernel reads K/V pages THROUGH the block table — the
-``(B, nb)`` page list and the ``(B,)`` position vector are scalar-
-prefetched (``PrefetchScalarGridSpec``) so the BlockSpec index maps can
-steer each grid step's DMA at the page the slot actually owns.  Pages
-stream into a VMEM scratch gather buffer; at the slot's last page the
-kernel runs the masked attend over the full gathered sequence.  The XLA
+``(B, nb)`` page list, the ``(B,)`` position vector and the layer index
+are scalar-prefetched (``PrefetchScalarGridSpec``) so the BlockSpec
+index maps can steer each grid step's DMA at the page the slot actually
+owns, in the layer's slab of the stacked ``(groups, n_pages, ...)``
+pool: the layer scan carries the whole pool and never copies a slab
+out for the kernel.  Pages stream into a VMEM scratch gather buffer; at
+the slot's last page the kernel runs the masked attend over the full
+gathered sequence.  The XLA
 path this replaces (``attention._gather_pages`` + ``decode_attention``)
 materializes a contiguous ``(B, nb * page, ...)`` HBM copy of every
 slot's pages per layer per step; here the gather lives only in VMEM.
@@ -40,8 +43,8 @@ NEG_INF = -1e30
 
 
 # ----------------------------- decode: GQA -----------------------------------
-def _decode_gqa_kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
-                       k_s, v_s, *, page: int, nb: int):
+def _decode_gqa_kernel(bt_ref, pos_ref, layer_ref, q_ref, k_ref, v_ref,
+                       o_ref, k_s, v_s, *, page: int, nb: int):
     """Grid (B, nb), pages innermost.  Each step DMAs one page of K/V
     (selected by the block-table index maps) and scatters it, head by
     head, into the head-major (Hk, S, hd) gather scratch; the last page
@@ -71,16 +74,18 @@ def _decode_gqa_kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
         )
 
 
-def paged_decode_gqa_pallas(q, k_pages, v_pages, block_table, pos,
+def paged_decode_gqa_pallas(q, k_pages, v_pages, block_table, pos, layer,
                             interpret: bool = False):
     """Fused paged GQA decode.
 
-    q: (B, 1, H, hd); k_pages/v_pages: (n_pages, page, Hk, hd[v]);
-    block_table: (B, nb) int32 page ids; pos: (B,) int32 per-row
-    lengths.  Returns (B, 1, H, hdv) f32 — bitwise identical to
-    ``decode_attention(q, gather(k), gather(v), pos)``.  Rows whose
-    table points at the reserved garbage page 0 (inactive slots, pos
-    clamped to 0) are handled by the mask exactly as in the reference.
+    q: (B, 1, H, hd); k_pages/v_pages: (groups, n_pages, page, Hk,
+    hd[v]), the stacked pool; block_table: (B, nb) int32 page ids; pos:
+    (B,) int32 per-row lengths; layer: int32 scalar, the slab of
+    ``groups`` to read.  Returns (B, 1, H, hdv) f32 — bitwise identical
+    to ``decode_attention(q, gather(k[layer]), gather(v[layer]), pos)``.
+    Rows whose table points at the reserved garbage page 0 (inactive
+    slots, pos clamped to 0) are handled by the mask exactly as in the
+    reference.
 
     The gather scratch is head-major, (Hk, S, hd): with the head axis
     second-minor, as in the pages, a small Hk would be padded to a full
@@ -88,7 +93,7 @@ def paged_decode_gqa_pallas(q, k_pages, v_pages, block_table, pos,
     memory.
     """
     B, _, H, hd = q.shape
-    _, page, Hk, _ = k_pages.shape
+    _, _, page, Hk, _ = k_pages.shape
     hdv = v_pages.shape[-1]
     G = H // Hk
     nb = block_table.shape[1]
@@ -96,22 +101,21 @@ def paged_decode_gqa_pallas(q, k_pages, v_pages, block_table, pos,
     scale = 1.0 / math.sqrt(hd)
     qg = (q * scale).reshape(B, Hk, G, hd)
 
+    def page_idx(b, i, bt, ps, ly):
+        return (ly[0], bt[b, i], 0, 0, 0)
+
     out = pl.pallas_call(
         functools.partial(_decode_gqa_kernel, page=page, nb=nb),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(B, nb),
             in_specs=[
-                pl.BlockSpec((None, Hk, G, hd), lambda b, i, bt, ps: (b, 0, 0, 0)),
-                pl.BlockSpec(
-                    (None, page, Hk, hd), lambda b, i, bt, ps: (bt[b, i], 0, 0, 0)
-                ),
-                pl.BlockSpec(
-                    (None, page, Hk, hdv), lambda b, i, bt, ps: (bt[b, i], 0, 0, 0)
-                ),
+                pl.BlockSpec((None, Hk, G, hd), lambda b, i, bt, ps, ly: (b, 0, 0, 0)),
+                pl.BlockSpec((None, None, page, Hk, hd), page_idx),
+                pl.BlockSpec((None, None, page, Hk, hdv), page_idx),
             ],
             out_specs=pl.BlockSpec(
-                (None, Hk, G, hdv), lambda b, i, bt, ps: (b, 0, 0, 0)
+                (None, Hk, G, hdv), lambda b, i, bt, ps, ly: (b, 0, 0, 0)
             ),
             scratch_shapes=[
                 pltpu.VMEM((Hk, S, hd), k_pages.dtype),
@@ -120,13 +124,15 @@ def paged_decode_gqa_pallas(q, k_pages, v_pages, block_table, pos,
         ),
         out_shape=jax.ShapeDtypeStruct((B, Hk, G, hdv), jnp.float32),
         interpret=interpret,
-    )(block_table, pos, qg, k_pages, v_pages)
+    )(block_table, pos, jnp.reshape(layer, (1,)).astype(jnp.int32), qg,
+      k_pages, v_pages)
     return out.reshape(B, 1, H, hdv)
 
 
 # ----------------------------- decode: MLA -----------------------------------
-def _decode_mla_kernel(bt_ref, pos_ref, qa_ref, qr_ref, c_ref, r_ref, o_ref,
-                       c_s, r_s, *, page: int, nb: int, scale: float):
+def _decode_mla_kernel(bt_ref, pos_ref, layer_ref, qa_ref, qr_ref, c_ref,
+                       r_ref, o_ref, c_s, r_s, *, page: int, nb: int,
+                       scale: float):
     b = pl.program_id(0)
     i = pl.program_id(1)
     c_s[pl.ds(i * page, page)] = c_ref[0]
@@ -152,40 +158,43 @@ def _decode_mla_kernel(bt_ref, pos_ref, qa_ref, qr_ref, c_ref, r_ref, o_ref,
 
 
 def paged_decode_mla_pallas(q_abs, q_rope, ckv_pages, krope_pages,
-                            block_table, pos, scale: float,
+                            block_table, pos, layer, scale: float,
                             interpret: bool = False):
     """Fused paged absorbed-MLA decode, in the compressed c_kv space.
 
     q_abs: (B, 1, H, r) f32 absorbed queries; q_rope: (B, 1, H, dr);
-    ckv_pages: (n_pages, page, r); krope_pages: (n_pages, page, dr).
+    ckv_pages: (groups, n_pages, page, r); krope_pages: (groups,
+    n_pages, page, dr); ``layer`` picks the slab, as in the GQA kernel.
     Returns the (B, 1, H, r) f32 context (the ``w_uv`` up-projection
     stays outside) — bitwise identical to ``mla_attend_core`` over the
     gathered per-slot views.  ``scale`` multiplies the SUMMED nope+rope
     scores, matching the reference's post-sum scaling."""
     B, _, H, r = q_abs.shape
     dr = q_rope.shape[-1]
-    _, page, _ = ckv_pages.shape
+    _, _, page, _ = ckv_pages.shape
     nb = block_table.shape[1]
     S = nb * page
     qa = q_abs.astype(jnp.float32).reshape(B, H, r)
     qr = q_rope.astype(jnp.float32).reshape(B, H, dr)
 
+    def page_idx(b, i, bt, ps, ly):
+        return (ly[0], bt[b, i], 0, 0)
+
+    def row_idx(b, i, bt, ps, ly):
+        return (b, 0, 0)
+
     out = pl.pallas_call(
         functools.partial(_decode_mla_kernel, page=page, nb=nb, scale=scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(B, nb),
             in_specs=[
-                pl.BlockSpec((1, H, r), lambda b, i, bt, ps: (b, 0, 0)),
-                pl.BlockSpec((1, H, dr), lambda b, i, bt, ps: (b, 0, 0)),
-                pl.BlockSpec(
-                    (1, page, r), lambda b, i, bt, ps: (bt[b, i], 0, 0)
-                ),
-                pl.BlockSpec(
-                    (1, page, dr), lambda b, i, bt, ps: (bt[b, i], 0, 0)
-                ),
+                pl.BlockSpec((1, H, r), row_idx),
+                pl.BlockSpec((1, H, dr), row_idx),
+                pl.BlockSpec((None, 1, page, r), page_idx),
+                pl.BlockSpec((None, 1, page, dr), page_idx),
             ],
-            out_specs=pl.BlockSpec((1, H, r), lambda b, i, bt, ps: (b, 0, 0)),
+            out_specs=pl.BlockSpec((1, H, r), row_idx),
             scratch_shapes=[
                 pltpu.VMEM((S, r), ckv_pages.dtype),
                 pltpu.VMEM((S, dr), krope_pages.dtype),
@@ -193,7 +202,8 @@ def paged_decode_mla_pallas(q_abs, q_rope, ckv_pages, krope_pages,
         ),
         out_shape=jax.ShapeDtypeStruct((B, H, r), jnp.float32),
         interpret=interpret,
-    )(block_table, pos, qa, qr, ckv_pages, krope_pages)
+    )(block_table, pos, jnp.reshape(layer, (1,)).astype(jnp.int32), qa, qr,
+      ckv_pages, krope_pages)
     return out.reshape(B, 1, H, r)
 
 

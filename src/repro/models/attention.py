@@ -220,10 +220,12 @@ def prefix_attention(
     return o.reshape(B, T, H, hdv)
 
 
-def _gather_pages(pages: jnp.ndarray, block_table: jnp.ndarray) -> jnp.ndarray:
-    """(n_pages, page, ...) pages + (B, nb) block table -> contiguous
-    per-slot views (B, nb * page, ...)."""
-    g = pages[block_table]                      # (B, nb, page, ...)
+def _gather_pages(pages: jnp.ndarray, block_table: jnp.ndarray,
+                  layer) -> jnp.ndarray:
+    """(groups, n_pages, page, ...) stacked pages + (B, nb) block table
+    -> contiguous per-slot views (B, nb * page, ...) of slab ``layer``,
+    in one gather: no slab is copied out of the stack first."""
+    g = pages[layer, block_table]               # (B, nb, page, ...)
     return g.reshape(g.shape[0], -1, *g.shape[3:])
 
 
@@ -304,39 +306,42 @@ def gqa_apply_decode(p, x, cfg, cache, pos, position_ids=None):
     return y, {"k": k_cache, "v": v_cache}
 
 
-def gqa_apply_decode_paged(p, x, cfg, cache, block_table, pos):
-    """Slot-decode through a paged KV pool: cache k/v are
-    (n_pages, page, Hk, hd) shared pages, ``block_table`` is the (B, nb)
-    per-slot page list, ``pos`` the (B,) per-row lengths.  The new K/V
-    scatters into page ``bt[b, pos // page]`` row ``pos % page`` (always
-    a page the slot owns alone — shared prefix pages are fully covered
-    by the prompt and decode writes start at the prompt end), then the
-    attention runs through ``kops.paged_decode_gqa``: on the XLA backend
+def gqa_apply_decode_paged(p, x, cfg, cache, layer, block_table, pos):
+    """Slot-decode through a paged KV pool: cache k/v are the stacked
+    (groups, n_pages, page, Hk, hd) shared pages, of which this layer
+    owns slab ``layer``; ``block_table`` is the (B, nb) per-slot page
+    list, ``pos`` the (B,) per-row lengths.  The new K/V scatters in
+    place into slab ``layer``, page ``bt[b, pos // page]`` row
+    ``pos % page`` (always a page the slot owns alone — shared prefix
+    pages are fully covered by the prompt and decode writes start at the
+    prompt end), then the attention runs through
+    ``kops.paged_decode_gqa``: on the XLA backend
     the slot's pages gather into a contiguous (B, nb * page, ...) view
     for the same masked ``decode_attention`` the monolithic path runs;
     on the Pallas backend the fused kernel reads the pages through the
     block table in VMEM (bitwise identical)."""
     B = x.shape[0]
     q, k, v = gqa_qkv(p, x, cfg, pos[:, None])
-    page = cache["k"].shape[1]
+    page = cache["k"].shape[2]
     pg = jnp.take_along_axis(block_table, (pos // page)[:, None], axis=1)[:, 0]
     rw = pos % page
-    k_pages = cache["k"].at[pg, rw].set(k[:, 0].astype(cache["k"].dtype))
-    v_pages = cache["v"].at[pg, rw].set(v[:, 0].astype(cache["v"].dtype))
+    k_pages = cache["k"].at[layer, pg, rw].set(k[:, 0].astype(cache["k"].dtype))
+    v_pages = cache["v"].at[layer, pg, rw].set(v[:, 0].astype(cache["v"].dtype))
     o = kops.paged_decode_gqa(
-        q, k_pages, v_pages, block_table, pos, backend=cfg.attn_backend
+        q, k_pages, v_pages, block_table, pos, layer, backend=cfg.attn_backend
     )
     y = _out_proj(p, o.reshape(B, 1, -1).astype(x.dtype))
     return y, {"k": k_pages, "v": v_pages}
 
 
-def gqa_apply_prefix(p, x, cfg, cache, block_table, ctx_len, wr_pg, wr_rw,
-                     use_context: bool = True):
+def gqa_apply_prefix(p, x, cfg, cache, layer, block_table, ctx_len, wr_pg,
+                     wr_rw, use_context: bool = True):
     """Paged (burst) prefill of tail tokens over an optional reused
-    prefix: queries sit at absolute positions ``ctx_len[b] + t`` (RoPE),
-    attend the gathered context pages (valid where ``j < ctx_len``) plus
-    the tail causally, and the tail K/V scatters into the slot's pages
-    at ``(wr_pg, wr_rw)`` (right-pad writes land in the garbage page).
+    prefix, in slab ``layer`` of the stacked pool: queries sit at
+    absolute positions ``ctx_len[b] + t`` (RoPE), attend the gathered
+    context pages (valid where ``j < ctx_len``) plus the tail causally,
+    and the tail K/V scatters in place into the slot's pages at
+    ``(layer, wr_pg, wr_rw)`` (right-pad writes land in the garbage page).
     The context is read from the *pre-write* pool — a request never
     shares a page with a burst member whose fill is still pending (the
     scheduler splits such bursts), so the gather sees only pages filled
@@ -350,15 +355,15 @@ def gqa_apply_prefix(p, x, cfg, cache, block_table, ctx_len, wr_pg, wr_rw,
     q, k, v = gqa_qkv(p, x, cfg, ctx_len[:, None])
     if use_context:
         with common.precision_island("attn"):
-            k_ctx = _gather_pages(cache["k"], block_table).astype(k.dtype)
-            v_ctx = _gather_pages(cache["v"], block_table).astype(v.dtype)
+            k_ctx = _gather_pages(cache["k"], block_table, layer).astype(k.dtype)
+            v_ctx = _gather_pages(cache["v"], block_table, layer).astype(v.dtype)
     else:
         k_ctx = v_ctx = None
     o = kops.prefix_prefill(
         q, k_ctx, v_ctx, k, v, ctx_len, backend=cfg.attn_backend
     )
-    k_pages = cache["k"].at[wr_pg, wr_rw].set(k.astype(cache["k"].dtype))
-    v_pages = cache["v"].at[wr_pg, wr_rw].set(v.astype(cache["v"].dtype))
+    k_pages = cache["k"].at[layer, wr_pg, wr_rw].set(k.astype(cache["k"].dtype))
+    v_pages = cache["v"].at[layer, wr_pg, wr_rw].set(v.astype(cache["v"].dtype))
     y = _out_proj(p, o.reshape(B, T, -1).astype(x.dtype))
     return y, {"k": k_pages, "v": v_pages}
 
@@ -524,23 +529,26 @@ def mla_apply_decode(p, x, cfg, cache, pos):
     return y, {"c_kv": ckv, "k_rope": krope}
 
 
-def mla_apply_decode_paged(p, x, cfg, cache, block_table, pos):
+def mla_apply_decode_paged(p, x, cfg, cache, layer, block_table, pos):
     """Absorbed MLA decode through a paged compressed cache: pages are
-    (n_pages, page, r_kv)/(n_pages, page, d_rope); the new row scatters
-    into the slot's page at ``pos``, then ``kops.paged_decode_mla`` runs
+    the stacked (groups, n_pages, page, r_kv)/(groups, n_pages, page,
+    d_rope); the new row scatters in place into slab ``layer``, the
+    slot's page at ``pos``, then ``kops.paged_decode_mla`` runs
     ``mla_attend_core`` over the block table — via an XLA gather or the
     fused Pallas kernel, per ``cfg.attn_backend``."""
     B = x.shape[0]
     q_nope, q_rope = _mla_q(p, x, cfg, pos[:, None])
     c_new, kr_new = _mla_ckv(p, x, cfg, pos[:, None])
-    page = cache["c_kv"].shape[1]
+    page = cache["c_kv"].shape[2]
     pg = jnp.take_along_axis(block_table, (pos // page)[:, None], axis=1)[:, 0]
     rw = pos % page
-    ckv_pages = cache["c_kv"].at[pg, rw].set(c_new[:, 0].astype(cache["c_kv"].dtype))
-    kr_pages = cache["k_rope"].at[pg, rw].set(kr_new[:, 0].astype(cache["k_rope"].dtype))
+    ckv_pages = cache["c_kv"].at[layer, pg, rw].set(
+        c_new[:, 0].astype(cache["c_kv"].dtype))
+    kr_pages = cache["k_rope"].at[layer, pg, rw].set(
+        kr_new[:, 0].astype(cache["k_rope"].dtype))
     q_abs, scale, w_uv = _mla_absorb_q(p, cfg, q_nope)
     ctx = kops.paged_decode_mla(
-        q_abs, q_rope, ckv_pages, kr_pages, block_table, pos, scale,
+        q_abs, q_rope, ckv_pages, kr_pages, block_table, pos, layer, scale,
         backend=cfg.attn_backend,
     )
     with common.precision_island("attn"):
@@ -549,9 +557,10 @@ def mla_apply_decode_paged(p, x, cfg, cache, block_table, pos):
     return y, {"c_kv": ckv_pages, "k_rope": kr_pages}
 
 
-def mla_apply_prefix(p, x, cfg, cache, block_table, ctx_len, wr_pg, wr_rw,
-                     use_context: bool = True):
-    """Paged (burst) MLA prefill over an optional reused prefix: per-head
+def mla_apply_prefix(p, x, cfg, cache, layer, block_table, ctx_len, wr_pg,
+                     wr_rw, use_context: bool = True):
+    """Paged (burst) MLA prefill over an optional reused prefix, in slab
+    ``layer`` of the stacked pool: per-head
     K/V are reconstructed from the compressed cache for BOTH the gathered
     context pages and the tail (exactly as ``mla_apply_train``
     reconstructs them for a full prompt), then one ``prefix_attention``
@@ -568,10 +577,10 @@ def mla_apply_prefix(p, x, cfg, cache, block_table, ctx_len, wr_pg, wr_rw,
     if use_context:
         with common.precision_island("attn"):
             ckv_ctx = _gather_pages(
-                cache["c_kv"], block_table
+                cache["c_kv"], block_table, layer
             ).astype(c_kv.dtype)
             kr_ctx = _gather_pages(
-                cache["k_rope"], block_table
+                cache["k_rope"], block_table, layer
             ).astype(k_rope.dtype)
         L = ckv_ctx.shape[1]
         c_all = jnp.concatenate([ckv_ctx, c_kv], axis=1)     # (B, L+T, rkv)
@@ -596,7 +605,9 @@ def mla_apply_prefix(p, x, cfg, cache, block_table, ctx_len, wr_pg, wr_rw,
         backend=cfg.attn_backend,
     )
 
-    ckv_pages = cache["c_kv"].at[wr_pg, wr_rw].set(c_kv.astype(cache["c_kv"].dtype))
-    kr_pages = cache["k_rope"].at[wr_pg, wr_rw].set(k_rope.astype(cache["k_rope"].dtype))
+    ckv_pages = cache["c_kv"].at[layer, wr_pg, wr_rw].set(
+        c_kv.astype(cache["c_kv"].dtype))
+    kr_pages = cache["k_rope"].at[layer, wr_pg, wr_rw].set(
+        k_rope.astype(cache["k_rope"].dtype))
     y = _out_proj(p, o.reshape(B, T, -1).astype(x.dtype))
     return y, {"c_kv": ckv_pages, "k_rope": kr_pages}

@@ -150,38 +150,42 @@ def _block_decode(bp, x, cfg: LMConfig, mk: str, fk: str, cache, pos, position_i
     return _apply_ffn(bp, x + y, cfg, fk), cache
 
 
-def _block_decode_paged(bp, x, cfg: LMConfig, mk: str, fk: str, cache,
+def _block_decode_paged(bp, x, cfg: LMConfig, mk: str, fk: str, cache, l,
                         block_tables, pos):
-    """One decode block over a paged pool: attention mixers read/write
-    shared pages through the block table; SSM mixers keep per-slot O(1)
-    state (rows [0, B) of the pool's n_slots+1 rows — the last row is
-    the garbage slot that absorbs burst-padding prefill writes)."""
+    """One decode block over a paged pool: ``cache`` is the block's pool
+    entry stacked over the scan's groups, of which layer ``l`` is this
+    block's, updated in place.  Attention mixers read/write shared pages
+    through the block table; SSM mixers keep per-slot O(1) state (rows
+    [0, B) of the pool's n_slots+1 rows — the last row is the garbage
+    slot that absorbs burst-padding prefill writes)."""
     B = x.shape[0]
     h = norm_apply(bp["ln1"], x, cfg.norm)
     if mk == "gqa":
         y, cache = attn.gqa_apply_decode_paged(
-            bp["mixer"], h, cfg, cache, block_tables, pos
+            bp["mixer"], h, cfg, cache, l, block_tables, pos
         )
     elif mk == "mla":
         y, cache = attn.mla_apply_decode_paged(
-            bp["mixer"], h, cfg, cache, block_tables, pos
+            bp["mixer"], h, cfg, cache, l, block_tables, pos
         )
     else:
         y, new = ssm.mamba_step(
             bp["mixer"], h, cfg,
-            {"h": cache["h"][:B], "conv": cache["conv"][:B]},
+            {"h": cache["h"][l, :B], "conv": cache["conv"][l, :B]},
         )
         cache = {
-            "h": cache["h"].at[:B].set(new["h"]),
-            "conv": cache["conv"].at[:B].set(new["conv"].astype(cache["conv"].dtype)),
+            "h": cache["h"].at[l, :B].set(new["h"]),
+            "conv": cache["conv"].at[l, :B].set(
+                new["conv"].astype(cache["conv"].dtype)),
         }
     return _apply_ffn(bp, x + y, cfg, fk), cache
 
 
-def _block_prefill_paged(bp, x, cfg: LMConfig, mk: str, fk: str, cache,
+def _block_prefill_paged(bp, x, cfg: LMConfig, mk: str, fk: str, cache, l,
                          block_tables, ctx_len, tail_valid, wr_pg, wr_rw,
                          slots, use_context: bool):
-    """One paged-prefill block: attention mixers attend [reused prefix
+    """One paged-prefill block over layer ``l`` of the block's stacked
+    pool entry, updated in place: attention mixers attend [reused prefix
     pages ; causal tail] and scatter the tail K/V into the slot's pages;
     SSM mixers run the chunked mix over the tail (per-row valid_len) and
     scatter the post-prompt state at ``slots`` (prefix reuse never
@@ -192,13 +196,13 @@ def _block_prefill_paged(bp, x, cfg: LMConfig, mk: str, fk: str, cache,
     h = norm_apply(bp["ln1"], x, cfg.norm)
     if mk == "gqa":
         y, cache = attn.gqa_apply_prefix(
-            bp["mixer"], h, cfg, cache, block_tables, ctx_len, wr_pg, wr_rw,
-            use_context,
+            bp["mixer"], h, cfg, cache, l, block_tables, ctx_len, wr_pg,
+            wr_rw, use_context,
         )
     elif mk == "mla":
         y, cache = attn.mla_apply_prefix(
-            bp["mixer"], h, cfg, cache, block_tables, ctx_len, wr_pg, wr_rw,
-            use_context,
+            bp["mixer"], h, cfg, cache, l, block_tables, ctx_len, wr_pg,
+            wr_rw, use_context,
         )
     else:
         y, state = ssm.mamba_mix(
@@ -206,8 +210,9 @@ def _block_prefill_paged(bp, x, cfg: LMConfig, mk: str, fk: str, cache,
             training=False, valid_len=tail_valid,
         )
         cache = {
-            "h": cache["h"].at[slots].set(state["h"]),
-            "conv": cache["conv"].at[slots].set(state["conv"].astype(cache["conv"].dtype)),
+            "h": cache["h"].at[l, slots].set(state["h"]),
+            "conv": cache["conv"].at[l, slots].set(
+                state["conv"].astype(cache["conv"].dtype)),
         }
     return _apply_ffn(bp, x + y, cfg, fk), cache
 
@@ -476,6 +481,15 @@ def init_paged_pool(cfg: LMConfig, n_slots: int, n_pages: int, page_size: int,
     return jax.jit(make, out_shardings=layout)()
 
 
+def _group_index(cfg: LMConfig):
+    """The layer scan's group indices, scanned beside the blocks: each
+    step indexes the pool it carries (stacked over the groups) at its
+    own group and updates it in place.  Scanning the pool as ``xs`` and
+    returning it as ``ys`` would copy every layer's slab out, into a
+    fresh stacked pool, and that pool back into the donated one."""
+    return jnp.arange(cfg.n_layers // cfg.scan_period(), dtype=jnp.int32)
+
+
 def decode_step_paged(params, inputs, pos, pool, block_tables, cfg: LMConfig):
     """One decode step over all slots, reading/writing attention caches
     THROUGH the block tables (``(B, max_len // page_size)`` int32 page
@@ -492,21 +506,23 @@ def decode_step_paged(params, inputs, pos, pool, block_tables, cfg: LMConfig):
     period = cfg.scan_period()
     kinds = [(cfg.mixer_kind(i), cfg.ffn_of(i)) for i in range(period)]
 
-    def scan_body(x, xs):
-        gp, gcaches = xs
-        new_caches = []
+    def scan_body(carry, xs):
+        x, pool = carry
+        gp, l = xs
+        pool = list(pool)
         for p_i in range(period):
             mk, fk = kinds[p_i]
-            x, c = _block_decode_paged(
-                gp[p_i], x, cfg, mk, fk, gcaches[p_i], block_tables, pos
+            x, pool[p_i] = _block_decode_paged(
+                gp[p_i], x, cfg, mk, fk, pool[p_i], l, block_tables, pos
             )
-            new_caches.append(c)
-        return x, tuple(new_caches)
+        return (x, tuple(pool)), None
 
-    x, new_pool = jax.lax.scan(scan_body, x, (tuple(params["blocks"]), pool))
+    (x, pool), _ = jax.lax.scan(
+        scan_body, (x, pool), (tuple(params["blocks"]), _group_index(cfg))
+    )
     h = norm_apply(params["ln_f"], x, cfg.norm)
     logits = _head_logits(params, h, cfg)
-    return logits, new_pool
+    return logits, pool
 
 
 def prefill_paged(params, batch, cfg: LMConfig, pool, block_tables, slots,
@@ -549,22 +565,24 @@ def prefill_paged(params, batch, cfg: LMConfig, pool, block_tables, slots,
     period = cfg.scan_period()
     kinds = [(cfg.mixer_kind(i), cfg.ffn_of(i)) for i in range(period)]
 
-    def scan_body(x, xs):
-        gp, gcaches = xs
-        new_caches = []
+    def scan_body(carry, xs):
+        x, pool = carry
+        gp, l = xs
+        pool = list(pool)
         for p_i in range(period):
             mk, fk = kinds[p_i]
-            x, c = _block_prefill_paged(
-                gp[p_i], x, cfg, mk, fk, gcaches[p_i], block_tables,
+            x, pool[p_i] = _block_prefill_paged(
+                gp[p_i], x, cfg, mk, fk, pool[p_i], l, block_tables,
                 ctx_len, tail_valid, wr_pg, wr_rw, slots, use_context,
             )
-            new_caches.append(c)
-        return x, tuple(new_caches)
+        return (x, tuple(pool)), None
 
-    x, new_pool = jax.lax.scan(scan_body, x, (tuple(params["blocks"]), pool))
+    (x, pool), _ = jax.lax.scan(
+        scan_body, (x, pool), (tuple(params["blocks"]), _group_index(cfg))
+    )
     h = norm_apply(params["ln_f"], x, cfg.norm)
     logits = _head_logits(params, last_valid_hidden(h, tail_valid), cfg)
-    return new_pool, logits
+    return pool, logits
 
 
 def pool_nbytes(pool) -> int:

@@ -47,8 +47,9 @@ class TestPagedDecodeGQA:
         bt = _block_table(rng, B, nb, n_pages)
         q = jnp.asarray(rng.standard_normal((B, 1, Hk * G, hd)), jnp.float32)
         pos = jnp.asarray(rng.integers(0, nb * page, B), jnp.int32)
-        want = ops.paged_decode_gqa(q, kp, vp, bt, pos, backend="xla")
-        got = ops.paged_decode_gqa(q, kp, vp, bt, pos,
+        want = ops.paged_decode_gqa(q, kp[None], vp[None], bt, pos, 0,
+                                    backend="xla")
+        got = ops.paged_decode_gqa(q, kp[None], vp[None], bt, pos, 0,
                                    backend="pallas_interpret")
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
@@ -65,8 +66,9 @@ class TestPagedDecodeGQA:
         bt = jnp.asarray(bt)
         q = jnp.asarray(rng.standard_normal((B, 1, Hk * G, hd)), jnp.float32)
         pos = jnp.asarray([5, 0, nb * page - 1], jnp.int32)
-        want = ops.paged_decode_gqa(q, kp, vp, bt, pos, backend="xla")
-        got = ops.paged_decode_gqa(q, kp, vp, bt, pos,
+        want = ops.paged_decode_gqa(q, kp[None], vp[None], bt, pos, 0,
+                                    backend="xla")
+        got = ops.paged_decode_gqa(q, kp[None], vp[None], bt, pos, 0,
                                    backend="pallas_interpret")
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
@@ -89,10 +91,34 @@ class TestPagedDecodeGQA:
         pos[0] = 0                                      # fresh slot edge
         pos[-1] = nb * page - 1                         # full slot edge
         pos = jnp.asarray(pos)
-        want = ops.paged_decode_gqa(q, kp, vp, bt, pos, backend="xla")
-        got = ops.paged_decode_gqa(q, kp, vp, bt, pos,
+        want = ops.paged_decode_gqa(q, kp[None], vp[None], bt, pos, 0,
+                                    backend="xla")
+        got = ops.paged_decode_gqa(q, kp[None], vp[None], bt, pos, 0,
                                    backend="pallas_interpret")
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    @pytest.mark.parametrize("layer", [0, 2, 4])
+    def test_layer_index(self, layer):
+        """The kernel reads slab ``layer`` of a stacked pool whose groups
+        hold different data: it equals the XLA backend on the same pool
+        bitwise, and both equal the slab alone read as layer 0."""
+        rng = np.random.default_rng(11 + layer)
+        groups, B, Hk, G, hd, page, nb = 5, 3, 2, 4, 16, 8, 3
+        n_pages = 1 + B * nb + 2
+        kp, vp = _paged_kv(rng, groups * n_pages, page, Hk, hd, hd)
+        kp = kp.reshape(groups, n_pages, page, Hk, hd)
+        vp = vp.reshape(groups, n_pages, page, Hk, hd)
+        bt = _block_table(rng, B, nb, n_pages)
+        q = jnp.asarray(rng.standard_normal((B, 1, Hk * G, hd)), jnp.float32)
+        pos = jnp.asarray([0, 13, nb * page - 1], jnp.int32)
+        ly = jnp.int32(layer)
+        want = ops.paged_decode_gqa(q, kp, vp, bt, pos, ly, backend="xla")
+        got = ops.paged_decode_gqa(q, kp, vp, bt, pos, ly,
+                                   backend="pallas_interpret")
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        alone = ops.paged_decode_gqa(q, kp[layer][None], vp[layer][None], bt,
+                                     pos, 0, backend="xla")
+        np.testing.assert_array_equal(np.asarray(want), np.asarray(alone))
 
 
 # ============================ decode: MLA ====================================
@@ -112,10 +138,10 @@ class TestPagedDecodeMLA:
         qr = jnp.asarray(rng.standard_normal((B, 1, H, dr)), jnp.float32)
         pos = jnp.asarray(rng.integers(0, nb * page, B), jnp.int32)
         scale = 1.0 / np.sqrt(r + dr)
-        want = ops.paged_decode_mla(qa, qr, cp, rp, bt, pos, scale,
-                                    backend="xla")
-        got = ops.paged_decode_mla(qa, qr, cp, rp, bt, pos, scale,
-                                   backend="pallas_interpret")
+        want = ops.paged_decode_mla(qa, qr, cp[None], rp[None], bt, pos, 0,
+                                    scale, backend="xla")
+        got = ops.paged_decode_mla(qa, qr, cp[None], rp[None], bt, pos, 0,
+                                   scale, backend="pallas_interpret")
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
     @settings(max_examples=10, deadline=None)
@@ -132,10 +158,11 @@ class TestPagedDecodeMLA:
         qr = jnp.asarray(rng.standard_normal((B, 1, H, dr)), jnp.float32)
         pos = np.asarray(rng.integers(0, nb * page, B), np.int32)
         pos[0] = 0
-        want = ops.paged_decode_mla(qa, qr, cp, rp, bt, jnp.asarray(pos),
-                                    0.125, backend="xla")
-        got = ops.paged_decode_mla(qa, qr, cp, rp, bt, jnp.asarray(pos),
-                                   0.125, backend="pallas_interpret")
+        want = ops.paged_decode_mla(qa, qr, cp[None], rp[None], bt,
+                                    jnp.asarray(pos), 0, 0.125, backend="xla")
+        got = ops.paged_decode_mla(qa, qr, cp[None], rp[None], bt,
+                                   jnp.asarray(pos), 0, 0.125,
+                                   backend="pallas_interpret")
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
@@ -233,11 +260,11 @@ class TestBackendDispatch:
         pos = jnp.asarray([1, 3], jnp.int32)
         jaxpr = jax.make_jaxpr(
             lambda *a: ops.paged_decode_gqa(*a)
-        )(q, kp, vp, bt, pos)
+        )(q, kp[None], vp[None], bt, pos, 0)
         assert "pallas_call" not in str(jaxpr)
         np.testing.assert_array_equal(
-            np.asarray(ops.paged_decode_gqa(q, kp, vp, bt, pos)),
-            np.asarray(ops.paged_decode_gqa(q, kp, vp, bt, pos,
+            np.asarray(ops.paged_decode_gqa(q, kp[None], vp[None], bt, pos, 0)),
+            np.asarray(ops.paged_decode_gqa(q, kp[None], vp[None], bt, pos, 0,
                                             backend="xla")),
         )
 
